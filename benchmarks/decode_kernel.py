@@ -18,7 +18,7 @@ Writes ``results/bench/decode_kernel.json`` (the ``decode_kernel`` suite of
 ``results/dryrun/`` so ``benchmarks.roofline`` tabulates the decode kernel
 alongside the dry-run shapes: compute/memory seconds model one production
 decode step (tmux-12l-768h, 128 slots at 32k live positions) on the chip
-peaks from ``repro.launch.dryrun``, with ``useful_flops_frac`` the fraction
+peaks from ``repro.launch.peaks``, with ``useful_flops_frac`` the fraction
 of streamed K-block rows holding real keys (padding shrinks it).
 """
 from __future__ import annotations
@@ -33,7 +33,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.configs.base import ModelConfig, MuxConfig, ServingConfig
-from repro.launch.dryrun import HBM_BW, PEAK_FLOPS
+from repro.launch.peaks import V5E, peaks
 from repro.models import Backbone
 from repro.serving.engine import Engine
 from repro.serving.paging import pages_for
@@ -95,7 +95,8 @@ def _roofline_record(ps: int, kb: int, *, layers=12, d=768, heads=12,
     rows = n_blocks * kb * ps
     mem = batch * kv_heads * n_blocks * _block_bytes(kb, ps, hd, 2) * layers
     flops = 4 * live * hd * heads * batch * layers
-    c_s, m_s = flops / PEAK_FLOPS, mem / HBM_BW
+    chip = peaks(V5E)
+    c_s, m_s = flops / chip.bf16_flops, mem / chip.hbm_bytes
     return {
         "arch": "tmux-12l-768h", "shape": f"decode32k-ps{ps}-kb{kb}",
         "mesh": "pod", "mux_n": mux_n,

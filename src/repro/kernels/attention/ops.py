@@ -1,14 +1,11 @@
-"""Public op: causal flash attention (interpret=True on CPU)."""
+"""Public op: causal flash attention (interpreted off-TPU)."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.attention import kernel
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None):
     """q, k, v: (B, L, H, hd) -> (B, L, H, hd)."""
     return kernel.flash_attention(q, k, v, causal=causal, scale=scale,
-                                  interpret=_INTERPRET)
+                                  interpret=interpret_mode())

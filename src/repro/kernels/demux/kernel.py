@@ -43,7 +43,7 @@ def _demux_kernel(h_ref, p_ref, w1h_ref, w1p_ref, b1_ref, w2_ref, b2_ref,
             b2_ref[...].astype(jnp.float32), acc_ref.shape)
 
     h = h_ref[0].astype(jnp.float32)          # (BL, d)
-    p = p_ref[0, 0].astype(jnp.float32)       # (d,)
+    p = p_ref[0, 0].astype(jnp.float32)       # (1, d)
     w1h = w1h_ref[...].astype(jnp.float32)    # (d, BH)
     w1p = w1p_ref[...].astype(jnp.float32)
     z = h @ w1h + p @ w1p + b1_ref[...].astype(jnp.float32)  # (BL, BH)
@@ -58,7 +58,8 @@ def _demux_kernel(h_ref, p_ref, w1h_ref, w1p_ref, b1_ref, w2_ref, b2_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def index_embed_demux(mlp_params, h, index_embeds, *, interpret: bool = False):
     """2-layer shared demux MLP, fused.  h (B, L, d); p (B, N, d) ->
-    (B, N, L, d)."""
+    (B, N, L, d).  p enters as (B, N, 1, d) so its per-lane (1, d) block is
+    whole in its last two dims (Mosaic refuses a (1, d) block of (N, d))."""
     b, l, d = h.shape
     n = index_embeds.shape[1]
     w1 = mlp_params["l0"]["w"]
@@ -87,7 +88,7 @@ def index_embed_demux(mlp_params, h, index_embeds, *, interpret: bool = False):
         grid=(b, n, lpad // bl, n_hblocks),
         in_specs=[
             pl.BlockSpec((1, bl, d), lambda i, j, m, k: (i, m, 0)),     # h
-            pl.BlockSpec((1, 1, d), lambda i, j, m, k: (i, j, 0)),      # p
+            pl.BlockSpec((1, 1, 1, d), lambda i, j, m, k: (i, j, 0, 0)),  # p
             pl.BlockSpec((d, bh), lambda i, j, m, k: (0, k)),           # W1h
             pl.BlockSpec((d, bh), lambda i, j, m, k: (0, k)),           # W1p
             pl.BlockSpec((1, bh), lambda i, j, m, k: (0, k)),           # b1
@@ -98,8 +99,8 @@ def index_embed_demux(mlp_params, h, index_embeds, *, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((b, n, lpad, d), dt),
         scratch_shapes=[pltpu.VMEM((bl, d), jnp.float32)],
         interpret=interpret,
-    )(h, index_embeds.astype(dt), w1h.astype(dt), w1p.astype(dt),
-      b1.reshape(1, -1).astype(dt), w2.astype(dt),
+    )(h, index_embeds.astype(dt)[:, :, None, :], w1h.astype(dt),
+      w1p.astype(dt), b1.reshape(1, -1).astype(dt), w2.astype(dt),
       b2.reshape(1, -1).astype(dt))
     return out[:, :, :l, :]
 

@@ -1,4 +1,4 @@
-"""Public op: fused index-embed demux (interpret=True on CPU).
+"""Public op: fused index-embed demux (interpreted off-TPU).
 
 Reached through the strategy registry: ``IndexEmbedDemux.kernel_apply``
 (``repro.core.strategies.demux``) routes here when ``cfg.use_kernel`` is
@@ -7,11 +7,8 @@ fused-kernel 2-layer shape (``demux_layers != 2``).
 """
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.demux import kernel, ref
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def index_embed_demux(mlp_params, h, index_embeds):
@@ -19,7 +16,7 @@ def index_embed_demux(mlp_params, h, index_embeds):
     if set(mlp_params) != {"l0", "l1"}:
         return ref.index_embed_demux(mlp_params, h, index_embeds)
     return kernel.index_embed_demux(mlp_params, h, index_embeds,
-                                    interpret=_INTERPRET)
+                                    interpret=interpret_mode())
 
 
 def decode_demux(mlp_params, h, index_embeds):
@@ -30,4 +27,4 @@ def decode_demux(mlp_params, h, index_embeds):
     if set(mlp_params) != {"l0", "l1"}:
         return ref.index_embed_demux(mlp_params, h, index_embeds)
     return kernel.decode_demux(mlp_params, h, index_embeds,
-                               interpret=_INTERPRET)
+                               interpret=interpret_mode())

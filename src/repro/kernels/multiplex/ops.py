@@ -1,4 +1,4 @@
-"""Public op: fused Hadamard multiplexer (interpret=True on CPU).
+"""Public op: fused Hadamard multiplexer (interpreted off-TPU).
 
 Reached through the strategy registry: ``HadamardMux.kernel_apply``
 (``repro.core.strategies.linear``) routes here when ``cfg.use_kernel`` is
@@ -8,13 +8,10 @@ strategy-agnostic.
 """
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.multiplex import kernel
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def hadamard_mux(x, v):
     """x: (B, N, L, d); v: (N, d) -> (B, L, d)."""
-    return kernel.hadamard_mux(x, v, interpret=_INTERPRET)
+    return kernel.hadamard_mux(x, v, interpret=interpret_mode())

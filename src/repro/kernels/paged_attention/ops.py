@@ -1,4 +1,4 @@
-"""Public op: paged-attention decode (interpret=True on CPU).
+"""Public op: paged-attention decode (interpreted off-TPU).
 
 ``use_kernel=False`` (the default) routes through the jnp gather reference,
 which is bit-for-bit identical to the contiguous decode path; the Pallas
@@ -9,11 +9,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.paged_attention import kernel, ref
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def paged_attention(q, k_pages, v_pages, pos_pages, block_table, q_pos, *,
@@ -29,7 +26,7 @@ def paged_attention(q, k_pages, v_pages, pos_pages, block_table, q_pos, *,
         return kernel.paged_decode_attention(
             q, k_pages, v_pages, pos_pages, block_table, q_pos, scale=scale,
             causal=causal, window=window, kblock_pages=kblock_pages,
-            interpret=_INTERPRET)
+            interpret=interpret_mode())
     return ref.paged_attention(q, k_pages, v_pages, pos_pages, block_table,
                                q_pos, scale=scale, causal=causal,
                                window=window)

@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape ×
 mesh) combination with ShapeDtypeStruct inputs — no allocation — and record
 memory_analysis / cost_analysis / collective bytes for §Roofline.
 
-MUST be run as its own process (the two lines above lock jax to 512
-placeholder devices before any other import):
+MUST be run as its own process: run as a script it sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before jax starts
+(importing the module sets nothing):
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen1.5-4b \
         --shape train_4k --mesh pod [--mux-n 8] [--out results/dryrun]
@@ -16,6 +14,7 @@ placeholder devices before any other import):
 import argparse
 import dataclasses
 import json
+import os
 import re
 import sys
 import time
@@ -30,18 +29,11 @@ from repro.configs.registry import (ARCHS, get_config, get_smoke_config,
                                     long_500k_supported)
 from repro.launch import inputs as I
 from repro.launch.mesh import make_production_mesh
+from repro.launch.peaks import V5E, peaks
 from repro.models import Backbone
 from repro.sharding.specs import (cache_specs, mesh_info_from_mesh,
                                   param_specs, state_specs)
 from repro.training.trainer import Trainer, TrainConfig
-
-# ---------------------------------------------------------------------------
-# roofline constants (TPU v5e)
-# ---------------------------------------------------------------------------
-
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
 
 COLLECTIVE_RE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
@@ -192,11 +184,12 @@ def analyse(lowered, compiled, cfg: ModelConfig, shape: ShapeConfig,
     # the recorded numbers follow the spec's HLO_FLOPs / (chips × peak) form.
     flops = float(cost.get("flops", 0.0)) * n_chips
     hbm_bytes = float(cost.get("bytes accessed", 0.0)) * n_chips
-    t_compute = flops / (n_chips * PEAK_FLOPS)
-    t_memory = hbm_bytes / (n_chips * HBM_BW)
+    chip = peaks(V5E)
+    t_compute = flops / (n_chips * chip.bf16_flops)
+    t_memory = hbm_bytes / (n_chips * chip.hbm_bytes)
     # collective sizes parsed from the per-device HLO = bytes crossing each
-    # chip's links; one effective ~50 GB/s link per chip.
-    t_coll = coll.get("total", 0.0) / ICI_BW
+    # chip's links; one effective link per chip.
+    t_coll = coll.get("total", 0.0) / chip.ici_link_bytes
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dominant = max(terms, key=terms.get)
@@ -343,4 +336,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # Before the first backend use (the imports above make none), so this
+    # process sees 512 placeholder devices.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

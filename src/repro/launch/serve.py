@@ -1,4 +1,11 @@
-"""Production serving launcher: batched multiplexed decode on a device mesh.
+"""Serving launcher: batched multiplexed decode on the devices present.
+
+Full width on one chip (qwen1.5-4b's published widths, seeded random
+weights; ``chip_smoke.py`` drives exactly this):
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-4b --mux-n 8 \
+        --workload poisson --paged --page-size 128 --num-requests 16 \
+        --prompt-len 128 --gen 32
 
 Lock-step grid (the classic fixed-(B, N) wave):
 
@@ -37,7 +44,8 @@ percentiles and per-class deadline attainment):
 
 Replica router (multi-engine tier: R independent engine+scheduler replicas
 behind one front door, requests dispatched by a pluggable routing policy,
-stats aggregated across the fleet):
+stats aggregated across the fleet; without ``--mesh-shape`` replica i
+lives on device i mod the device count):
 
     PYTHONPATH=src python -m repro.launch.serve --smoke --workload poisson \
         --replicas 2 --router-policy least_loaded --report --gen 8
@@ -95,9 +103,18 @@ def _report_lines(stats) -> list:
     return lines
 
 
-def _run_lockstep(args, cfg, mesh, mi, jax, Backbone, Engine):
+def init_params(cfg):
+    """Seeded random weights, built on the device by one jitted program (at
+    full width, op-by-op init would dispatch thousands of small ops)."""
+    import jax
+    from repro.models import Backbone
+    return jax.jit(Backbone.init, static_argnums=1)(jax.random.PRNGKey(0),
+                                                     cfg)
+
+
+def _run_lockstep(args, cfg, mesh, mi, jax, Engine):
     key = jax.random.PRNGKey(0)
-    params = Backbone.init(key, cfg)
+    params = init_params(cfg)
     with mesh:
         eng = Engine(params, cfg, batch=args.batch,
                      max_len=args.prompt_len + args.gen + 1,
@@ -115,11 +132,10 @@ def _run_lockstep(args, cfg, mesh, mi, jax, Backbone, Engine):
           f"({streams * args.gen / dt:.0f} tok/s)")
 
 
-def _run_workload(args, cfg, mesh, mi, jax, Backbone, Engine):
+def _run_workload(args, cfg, mesh, mi, jax, Engine):
     from repro.serving.scheduler import (ContinuousScheduler, poisson_trace,
                                          static_batch_steps)
-    key = jax.random.PRNGKey(0)
-    params = Backbone.init(key, cfg)
+    params = init_params(cfg)
     n = max(cfg.mux.n, 1)
     max_total = args.prompt_len * 2 + args.gen * 4 + 1
     tracer = _make_tracer(args)
@@ -193,22 +209,26 @@ def _run_workload(args, cfg, mesh, mi, jax, Backbone, Engine):
         raise SystemExit(
             f"[serve] FAIL: only {stats.finished}/{args.num_requests} "
             f"requests completed")
+    return stats
 
 
-def _run_router(args, cfg, mesh, mi, jax, Backbone, Engine):
+def _run_router(args, cfg, mesh, mi, jax, Engine):
     """Poisson trace through the replica router: R independent
-    engine+scheduler replicas, load-aware dispatch, aggregated report."""
+    engine+scheduler replicas, load-aware dispatch, aggregated report.
+    With ``--mesh-shape`` every replica runs on that mesh; otherwise each
+    replica gets a device of its own (round-robin over the devices)."""
     from repro.serving.router import ReplicaRouter
     from repro.serving.scheduler import poisson_trace
-    key = jax.random.PRNGKey(0)
-    params = Backbone.init(key, cfg)
+    params = init_params(cfg)
     n = max(cfg.mux.n, 1)
     max_total = args.prompt_len * 2 + args.gen * 4 + 1
     tracer = _make_tracer(args)
+    placement = dict(mesh=mesh, mesh_info=mi) if args.mesh_shape \
+        else dict(devices=jax.devices())
     with mesh:
         router = ReplicaRouter.build(
             params, cfg, batch=args.batch, max_len=max_total,
-            replicas=args.replicas, tracer=tracer, mesh=mesh, mesh_info=mi)
+            replicas=args.replicas, tracer=tracer, **placement)
         trace = poisson_trace(
             args.num_requests, rate=args.rate, prompt_len=args.prompt_len,
             gen_len=args.gen, vocab=cfg.vocab, max_total=max_total,
@@ -241,9 +261,12 @@ def _run_router(args, cfg, mesh, mi, jax, Backbone, Engine):
         raise SystemExit(
             f"[serve] FAIL: only {stats.finished}/{args.num_requests} "
             f"requests completed")
+    return stats
 
 
 def main(argv=None):
+    """Parse ``argv`` and serve; returns the workload's SchedulerStats /
+    RouterStats (None for the lock-step grid)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tmux-12l-768h")
     ap.add_argument("--mux-n", type=int, default=8)
@@ -253,10 +276,13 @@ def main(argv=None):
                     help="prompt tokens (default: 16 lock-step, 4 workload "
                          "— continuous ramps prompts through decode steps)")
     ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--device-count", type=int, default=0)
-    ap.add_argument("--mesh-shape", default="")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU-runnable)")
+    ap.add_argument("--device-count", type=int, default=0,
+                    help="force N host devices (CPU mesh emulation)")
+    ap.add_argument("--mesh-shape", default="",
+                    help="data,model mesh over the devices present "
+                         "(default 1,1)")
     # continuous-batching workload replay
     ap.add_argument("--workload", choices=["none", "poisson"], default="none",
                     help="replay a Poisson arrival trace through the "
@@ -278,7 +304,7 @@ def main(argv=None):
                          "ramps (1 = classic one-token ramp)")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route paged decode attention through the Pallas "
-                         "kernel (interpret mode off-TPU) instead of the "
+                         "kernel (interpreted off-TPU) instead of the "
                          "jnp gather reference")
     ap.add_argument("--kblock-pages", type=int, default=1,
                     help="block-table entries the paged kernel spans per "
@@ -347,20 +373,13 @@ def main(argv=None):
 
     import jax
     from repro.configs.registry import get_config, get_smoke_config
-    from repro.launch.mesh import make_production_mesh, make_test_mesh
-    from repro.models import Backbone
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import Engine
     from repro.sharding.specs import mesh_info_from_mesh
 
-    if args.mesh_shape:
-        shape = tuple(int(x) for x in args.mesh_shape.split(","))
-        mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
-    elif args.smoke and len(jax.devices()) == 1:
-        # CPU-CI smoke on a single device: test mesh with production axis
-        # names.  Multi-device hosts keep the production-mesh requirement.
-        mesh = make_test_mesh()
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    enable_compile_cache()
+    mesh = make_mesh(tuple(int(x) for x in args.mesh_shape.split(",") if x))
     mi = mesh_info_from_mesh(mesh)
 
     getter = get_smoke_config if args.smoke else get_config
@@ -388,11 +407,10 @@ def main(argv=None):
           f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     if args.workload == "poisson" and args.replicas > 1:
-        _run_router(args, cfg, mesh, mi, jax, Backbone, Engine)
-    elif args.workload == "poisson":
-        _run_workload(args, cfg, mesh, mi, jax, Backbone, Engine)
-    else:
-        _run_lockstep(args, cfg, mesh, mi, jax, Backbone, Engine)
+        return _run_router(args, cfg, mesh, mi, jax, Engine)
+    if args.workload == "poisson":
+        return _run_workload(args, cfg, mesh, mi, jax, Engine)
+    _run_lockstep(args, cfg, mesh, mi, jax, Engine)
 
 
 if __name__ == "__main__":

@@ -1,9 +1,9 @@
-"""Production training launcher: pjit train loop on the active device mesh.
+"""Training launcher: pjit train loop on a mesh over the devices present.
 
-On real hardware this runs the same code the dry-run lowers — state sharded
-by repro/sharding specs (ZeRO-1 moments), batch sharded over (pod, data),
-DataMUX width from --mux-n.  On this CPU container use --device-count to
-emulate a small mesh end-to-end (actually executes, unlike the dry-run):
+It runs the same code the dry-run lowers — state sharded by
+repro/sharding specs (ZeRO-1 moments), batch sharded over (pod, data),
+DataMUX width from --mux-n.  On a CPU host use --device-count to emulate a
+small mesh end-to-end (actually executes, unlike the dry-run):
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b \
         --smoke --device-count 4 --mesh-shape 2,2 --steps 20 --mux-n 4
@@ -22,11 +22,11 @@ def main(argv=None):
     ap.add_argument("--seq-len", type=int, default=32)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-runnable)")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--device-count", type=int, default=0,
                     help="force N host devices (CPU mesh emulation)")
     ap.add_argument("--mesh-shape", default="",
-                    help="data,model (defaults to production 16,16)")
+                    help="data,model mesh over the devices present "
+                         "(default 1,1)")
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args(argv)
 
@@ -42,16 +42,14 @@ def main(argv=None):
     from repro.configs.registry import get_config, get_smoke_config
     from repro.data.pipeline import mux_batches
     from repro.data.synthetic import RetrievalTask
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.sharding.specs import mesh_info_from_mesh, state_specs
     from repro.training.trainer import Trainer, TrainConfig
     from repro.checkpoint.io import save_checkpoint
 
-    if args.mesh_shape:
-        shape = tuple(int(x) for x in args.mesh_shape.split(","))
-        mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    enable_compile_cache()
+    mesh = make_mesh(tuple(int(x) for x in args.mesh_shape.split(",") if x))
     mi = mesh_info_from_mesh(mesh)
     print(f"[train] mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 

@@ -32,10 +32,12 @@ def xavier_uniform():
 
 
 def scaled_normal(fan_in: int):
-    """1/sqrt(fan_in) normal — standard transformer projection init."""
+    """1/sqrt(fan_in) normal — standard transformer projection init.  The
+    scale is a Python float, so the result keeps ``dtype`` (a NumPy scalar
+    would promote bf16 weights to f32)."""
 
     def init(key, shape, dtype=jnp.float32):
-        return jax.random.normal(key, shape, dtype) / np.sqrt(fan_in)
+        return jax.random.normal(key, shape, dtype) / float(np.sqrt(fan_in))
 
     return init
 

@@ -56,9 +56,20 @@ class ServeState:
 
 
 class Engine:
+    """``device``: put the params on this one device, so every cache the
+    engine builds from them lives there too (one-device replicas behind
+    ``ReplicaRouter``); leave it None to keep the params where they are —
+    and do not combine it with a ``mesh``."""
+
     def __init__(self, params, cfg: ModelConfig, *, batch: int, max_len: int,
-                 mesh=None, mesh_info: MeshInfo = SINGLE, jit: bool = True):
+                 mesh=None, mesh_info: MeshInfo = SINGLE, jit: bool = True,
+                 device=None):
+        if device is not None:
+            if mesh is not None:
+                raise ValueError("Engine takes a device or a mesh, not both")
+            params = jax.device_put(params, device)
         self.params = params
+        self.device = device
         self.cfg = cfg
         self.batch = batch
         self.max_len = max_len + cfg.mux.prefix_len
@@ -203,8 +214,9 @@ class Engine:
             cache, index_embeds = self._prime(
                 self.params, prime_len=(p if compact else self.max_len))
         else:
-            cache = Backbone.init_cache(cfg, self.batch,
-                                        1 if compact else self.max_len)
+            with jax.default_device(self.device):
+                cache = Backbone.init_cache(cfg, self.batch,
+                                            1 if compact else self.max_len)
             index_embeds = None
         pos = jnp.full((self.batch,), p, jnp.int32)
         return ServeState(cache=cache, pos=pos, index_embeds=index_embeds,
@@ -251,7 +263,8 @@ class Engine:
                 self.params["demux"], cfg.mux, width)
         serve_len = self.max_len - cfg.mux.prefix_len
         eng = Engine(params, vcfg, batch=batch, max_len=serve_len,
-                     mesh=self.mesh, mesh_info=self.mesh_info, jit=self._jit)
+                     mesh=self.mesh, mesh_info=self.mesh_info, jit=self._jit,
+                     device=self.device)
         eng.tracer = self.tracer
         return eng
 

@@ -51,6 +51,7 @@ import collections
 import dataclasses
 from typing import Callable, Optional, Sequence, TypeVar
 
+import jax
 import numpy as np
 
 from repro.serving.policies import SloClasses
@@ -227,9 +228,10 @@ class SloHeadroomRouting(RoutingPolicy):
 class RouterStats:
     """Cross-replica aggregate of the per-replica ``SchedulerStats``.
 
-    ``router_steps`` is the router clock — the wall-parallel step count
-    (replicas step concurrently on their own devices in production, so
-    completed tokens *per router step* is the scaling measure).
+    ``router_steps`` is the router clock — one tick steps every (busy)
+    replica once, each on its own device when the router was built with
+    ``devices``, so completed tokens *per router step* is the scaling
+    measure.
     ``decode_steps`` sums every replica's actual steps (total device work).
     TTFT percentiles and ``per_class`` deadline attainment are computed
     over the union of finished requests, in router-clock units."""
@@ -306,11 +308,13 @@ class ReplicaRouter:
     def build(cls, params, cfg, *, batch: int, max_len: int,
               replicas: Optional[int] = None, overrides: Optional[dict] = None,
               policy=None, sync: Optional[bool] = None, tracer=None,
-              **engine_kwargs) -> "ReplicaRouter":
-        """R replicas over one shared param set.  ``overrides`` maps a
-        replica index to either a full ModelConfig or just a ServingConfig
-        for that replica (heterogeneous fleets: paged next to contiguous,
-        different pools/policies)."""
+              devices: Sequence = (), **engine_kwargs) -> "ReplicaRouter":
+        """R replicas over one param set.  ``overrides`` maps a replica
+        index to either a full ModelConfig or just a ServingConfig for that
+        replica (heterogeneous fleets: paged next to contiguous, different
+        pools/policies).  ``devices``: replica i gets its own copy of the
+        params, its cache and its page pool on ``devices[i % len(devices)]``
+        (empty: every replica shares ``params`` where they are)."""
         from repro.serving.engine import Engine
         r = cfg.serving.replicas if replicas is None else replicas
         scheds = []
@@ -320,9 +324,12 @@ class ReplicaRouter:
             if ov is not None:
                 c = ov if isinstance(ov, type(cfg)) \
                     else dataclasses.replace(cfg, serving=ov)
-            scheds.append(ContinuousScheduler(
-                Engine(params, c, batch=batch, max_len=max_len,
-                       **engine_kwargs)))
+            dev = devices[i % len(devices)] if devices else None
+            # Caches built eagerly at construction land on ``dev`` too.
+            with jax.default_device(dev):
+                scheds.append(ContinuousScheduler(
+                    Engine(params, c, batch=batch, max_len=max_len,
+                           device=dev, **engine_kwargs)))
         return cls(scheds, policy=policy, sync=sync, tracer=tracer)
 
     # -- queue ----------------------------------------------------------------

@@ -34,6 +34,7 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs.registry import get_smoke_config
+        from repro.launch.mesh import make_mesh
         from repro.sharding.specs import mesh_info_from_mesh, state_specs
         from repro.training.trainer import Trainer, TrainConfig
 
@@ -48,7 +49,7 @@ def test_sharded_train_step_matches_single_device():
             jax.device_put(state), batch, key)
 
         # (2, 2) mesh with explicit shardings
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2))
         mi = mesh_info_from_mesh(mesh)
         specs = state_specs(state, mi)
         sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,

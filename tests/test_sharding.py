@@ -6,7 +6,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import ARCHS, get_smoke_config
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import Backbone
 from repro.sharding.specs import (cache_specs, mesh_info_from_mesh,
                                   param_specs, state_specs)
@@ -31,7 +31,7 @@ def _axes_valid(spec, leaf, mesh_axes=("pod", "data", "model")):
 def test_param_specs_structurally_valid(key, arch):
     cfg = get_smoke_config(arch, mux_n=2)
     params = Backbone.init(key, cfg)
-    mesh = make_test_mesh()
+    mesh = make_mesh()
     mi = mesh_info_from_mesh(mesh)
     specs = param_specs(params, mi)
     jax.tree.map(lambda s, l: _axes_valid(s, l), specs, params)
@@ -42,7 +42,7 @@ def test_param_specs_structurally_valid(key, arch):
 def test_cache_specs_structurally_valid(arch):
     cfg = get_smoke_config(arch, mux_n=1)
     cache = Backbone.init_cache(cfg, 4, 32)
-    mesh = make_test_mesh()
+    mesh = make_mesh()
     mi = mesh_info_from_mesh(mesh)
     specs = cache_specs(cache, mi)
     jax.tree.map(lambda s, l: _axes_valid(s, l), specs, cache)
@@ -53,7 +53,7 @@ def test_state_specs_and_jit_train_step(key):
     code path the production dry-run exercises."""
     cfg = get_smoke_config("tmux-4l-768h", mux_n=2)
     tcfg = TrainConfig(task="lm", total_steps=10)
-    mesh = make_test_mesh()
+    mesh = make_mesh()
     mi = mesh_info_from_mesh(mesh)
     state = Trainer.init_state(key, cfg, tcfg)
     sspecs = state_specs(state, mi)
