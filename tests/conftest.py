@@ -1,10 +1,19 @@
 import inspect
+import os
 import random
 import sys
 import types
 
-import jax
-import pytest
+# XLA:CPU's multi-threaded Eigen contractions may split a small matmul's
+# reduction over the host's threads, so two programs of different shapes
+# (a prefix-only prime against a full prefill) agree bitwise on one host and
+# not on another.  One thread per contraction makes the CPU numerics the
+# same on every host.  Set before the first backend use.
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS", ""), "--xla_cpu_multi_thread_eigen=false"]))
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
 
 # Tests run on the single real CPU device (dry-run handles the 512-device
 # mesh in its own process; DESIGN.md §6).

@@ -90,7 +90,7 @@ def _ramp_then_decode(cfg, params, prompts, chunk, *, paged=False,
                               block_table=block_table,
                               chunk_lens=np.full(B, take, np.int32))
         alloc.adopt(st.cache)
-        pos += take
+        pos = pos + take
         if fed < LP:
             fed += take
         last = np.asarray(jnp.argmax(logits[:, :, take - 1], axis=-1))
@@ -118,7 +118,7 @@ def _ramp_sequential(cfg, params, prompts):
                         index_embeds=primed.index_embeds)
         logits, st = eng.step(st, tokens, lane_mask=ones)
         alloc.adopt(st.cache)
-        pos += 1
+        pos = pos + 1
         if fed < LP:
             fed += 1
         last = np.asarray(jnp.argmax(logits, axis=-1))
