@@ -12,20 +12,24 @@ requests per slot (hadamard mux, index_embed demux), served paged.
   serve    ``repro.launch.serve.main`` — Engine + ContinuousScheduler over
            the paged KV cache replaying a Poisson trace; fails unless every
            request completes.
-  kernels  the same configuration twice on fixed prompts: once through the
-           XLA reference path, once with the Pallas kernels on (paged decode
-           attention, fused decode demux, mux/demux kernels).  Each prompt
-           enters the paged cache through the scheduler's ramp (the serving
-           path's prefill), then 4 greedy decode steps follow.  The kernel
-           run is teacher-forced with the reference's tokens, and at every
-           emitted position ||kernel - reference|| / ||reference|| over the
-           vocabulary must stay within ``LOGIT_RTOL``.  The compiled kernel decode step must
-           contain ``tpu_custom_call`` (Mosaic kernels, not interpreted).
-  router   (``--four-chips``) four replicas behind ``ReplicaRouter``
-           (round_robin, lock-step), each with its params, cache and page
-           pool on its own chip; then each replica's sub-trace (its requests
-           with their arrival steps) is replayed on one chip, one replica at
-           a time.  Generated tokens must match request for request.
+  kernels  the same configuration on fixed prompts: once through the XLA
+           reference path, then with the Pallas kernels on (paged decode
+           attention, fused decode demux, mux/demux kernels) at each of
+           ``KBLOCK_PAGES``.  Each prompt spans three 128-position pages
+           and enters the paged cache through the scheduler's ramp (the
+           serving path's prefill), then 4 greedy decode steps follow, so
+           the kernel walks several block-table entries per sequence.  The
+           kernel runs are teacher-forced with the reference's tokens, and
+           at every emitted position ||kernel - reference|| / ||reference||
+           over the vocabulary must stay within ``LOGIT_RTOL``.  Each
+           compiled kernel decode step must contain ``tpu_custom_call``
+           (Mosaic kernels, not interpreted).
+  router   (``--four-chips``) ``repro.launch.serve.main`` with four
+           replicas behind ``ReplicaRouter`` (round_robin, lock-step), each
+           with its params, cache and page pool on its own chip; then each
+           replica's share of the trace (its requests with their arrival
+           steps) is replayed on one chip, one replica at a time.  Generated
+           tokens must match request for request.
 
 Exits non-zero on any failure, and before any phase when JAX's first device
 is not a TPU: there is no CPU fallback.  The last line of standard output is
@@ -35,7 +39,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import gc
 import json
 import os
 import sys
@@ -57,9 +60,7 @@ from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.launch.serve import init_params  # noqa: E402
 from repro.serving.engine import Engine  # noqa: E402
 from repro.serving.policies import SamplingPolicy, SloClasses  # noqa: E402
-from repro.serving.router import ReplicaRouter  # noqa: E402
-from repro.serving.scheduler import (ContinuousScheduler, Request,  # noqa: E402
-                                     poisson_trace)
+from repro.serving.scheduler import ContinuousScheduler, Request  # noqa: E402
 
 ARCH = "qwen1.5-4b"
 MUX_N = 8
@@ -68,20 +69,29 @@ BATCH = 2                      # serve.py's workload default: 2 slots x N lanes
 SERVE_ARGS = ["--arch", ARCH, "--mux-n", str(MUX_N), "--workload", "poisson",
               "--paged", "--page-size", str(PAGE_SIZE), "--batch", str(BATCH),
               "--num-requests", "16", "--prompt-len", "128", "--gen", "32"]
-# The kernels and router phases keep the serve phase's engine shape (same
-# cache and page-pool shapes, so the reference step is the serve phase's
-# program) with shorter requests.
-MAX_LEN = 128 * 2 + 32 * 4 + 1
-KERNEL_PROMPT = 16
+# The kernels phase keeps the serve phase's engine shape (same cache and
+# page-pool shapes, so the reference step is the serve phase's program).
+MAX_LEN = serve.workload_max_len(128, 32)
+# Three pages per prompt: the paged kernel must walk and merge several
+# block-table entries (one per K-block at kblock 1, all in one at kblock 4).
+KERNEL_PROMPT = 2 * PAGE_SIZE + 16
+KBLOCK_PAGES = (1, 4)
 DECODE_STEPS = 4
 # Relative L2 limit on each position's logits.  bf16 compute over 40
 # layers: the kernels keep f32 where the XLA path rounds to bf16 between
 # ops, so the two differ by bf16 rounding (about 1e-2 of the logits' norm);
 # a wrong page, mask or lane differs by the order of the logits themselves.
 LOGIT_RTOL = 0.05
-ROUTER_REQUESTS = 8
-ROUTER_PROMPT = 16
+REPLICAS = 4
+# Long enough that each slot spans two pages: the launcher's dense pool then
+# holds every slot's prefix page and a working page.
+ROUTER_PROMPT = 48
 ROUTER_GEN = 8
+ROUTER_ARGS = ["--arch", ARCH, "--mux-n", str(MUX_N), "--workload", "poisson",
+               "--paged", "--page-size", str(PAGE_SIZE), "--batch", str(BATCH),
+               "--num-requests", "8", "--prompt-len", str(ROUTER_PROMPT),
+               "--gen", str(ROUTER_GEN), "--replicas", str(REPLICAS),
+               "--router-sync", "--router-policy", "round_robin"]
 
 
 def say(msg: str) -> None:
@@ -168,83 +178,85 @@ def kernel_requests(cfg):
 def run_kernels(base_cfg) -> None:
     """Phase kernels: reference path vs kernel path on fixed prompts."""
     ref_cfg = paged_config(base_cfg)
-    ker_cfg = dataclasses.replace(
-        paged_config(base_cfg, use_kernel=True, fuse_demux=True),
-        mux=dataclasses.replace(base_cfg.mux, use_kernel=True))
     params = init_params(ref_cfg)
     slo = SloClasses(ref_cfg.serving.slo_classes)
 
     ref = Recorder(slo)
     ContinuousScheduler(Engine(params, ref_cfg, batch=BATCH, max_len=MAX_LEN),
                         sampling=ref).run(kernel_requests(ref_cfg))
-    ker = Recorder(slo, forced=ref.tokens)
-    eng = Engine(params, ker_cfg, batch=BATCH, max_len=MAX_LEN)
-    sched = ContinuousScheduler(eng, sampling=ker)
-    sched.run(kernel_requests(ker_cfg))
-    if set(ker.logits) != set(ref.logits):
-        fail("kernel and reference paths emitted different positions")
-    rel = max(float(np.linalg.norm(ker.logits[k] - v) / np.linalg.norm(v))
-              for k, v in ref.logits.items())
-    diff = max(float(np.abs(ker.logits[k] - v).max())
-               for k, v in ref.logits.items())
-    scale = max(float(np.abs(v).max()) for v in ref.logits.values())
-    say(f"kernels: {len(ref.logits)} lane-positions ({BATCH * base_cfg.mux.n}"
-        f" lanes x prefill + {DECODE_STEPS} decode steps); max |logit| "
-        f"{scale:.4f}, largest |kernel - reference| {diff:.4f}; "
-        f"largest |kernel - reference| / |reference| per position {rel:.5f} "
-        f"(limit {LOGIT_RTOL})")
-    if not rel <= LOGIT_RTOL:
-        fail(f"kernel logits differ from the reference by {rel:.5f} "
-             f"(relative L2) > {LOGIT_RTOL}")
+    for kblock in KBLOCK_PAGES:
+        ker_cfg = dataclasses.replace(
+            paged_config(base_cfg, use_kernel=True, fuse_demux=True,
+                         kblock_pages=kblock),
+            mux=dataclasses.replace(base_cfg.mux, use_kernel=True))
+        ker = Recorder(slo, forced=ref.tokens)
+        eng = Engine(params, ker_cfg, batch=BATCH, max_len=MAX_LEN)
+        sched = ContinuousScheduler(eng, sampling=ker)
+        sched.run(kernel_requests(ker_cfg))
+        if set(ker.logits) != set(ref.logits):
+            fail("kernel and reference paths emitted different positions")
+        rel = max(float(np.linalg.norm(ker.logits[k] - v) / np.linalg.norm(v))
+                  for k, v in ref.logits.items())
+        diff = max(float(np.abs(ker.logits[k] - v).max())
+                   for k, v in ref.logits.items())
+        scale = max(float(np.abs(v).max()) for v in ref.logits.values())
+        pages = -(-(base_cfg.mux.prefix_len + KERNEL_PROMPT + DECODE_STEPS)
+                  // PAGE_SIZE)
+        say(f"kernels kblock_pages={kblock}: {len(ref.logits)} lane-positions "
+            f"({BATCH * base_cfg.mux.n} lanes x prefill + {DECODE_STEPS} "
+            f"decode steps, {pages} pages per slot); max |logit| "
+            f"{scale:.4f}, largest |kernel - reference| {diff:.4f}; "
+            f"largest |kernel - reference| / |reference| per position "
+            f"{rel:.5f} (limit {LOGIT_RTOL})")
+        if not rel <= LOGIT_RTOL:
+            fail(f"kernel logits at kblock_pages={kblock} differ from the "
+                 f"reference by {rel:.5f} (relative L2) > {LOGIT_RTOL}")
 
-    c = sched.classes[0]
-    hlo = eng._step.lower(
-        eng.params, jnp.zeros((BATCH, ker_cfg.mux.n), jnp.int32),
-        c.allocator.cache, jnp.asarray(sched.pos), c.index_embeds, None,
-        jnp.asarray(sched.table.lane_mask()), c.allocator.block_table,
-        None).compile().as_text()
-    kernels = hlo.count("tpu_custom_call")
-    say(f"kernels: compiled decode step holds {kernels} tpu_custom_call "
-        f"ops")
-    if not kernels:
-        fail("the kernel decode step compiled without tpu_custom_call")
+        c = sched.classes[0]
+        hlo = eng._step.lower(
+            eng.params, jnp.zeros((BATCH, ker_cfg.mux.n), jnp.int32),
+            c.allocator.cache, jnp.asarray(sched.pos), c.index_embeds, None,
+            jnp.asarray(sched.table.lane_mask()), c.allocator.block_table,
+            None).compile().as_text()
+        kernels = hlo.count("tpu_custom_call")
+        say(f"kernels kblock_pages={kblock}: compiled decode step holds "
+            f"{kernels} tpu_custom_call ops")
+        if not kernels:
+            fail("the kernel decode step compiled without tpu_custom_call")
 
 
 def run_router(base_cfg, devices) -> None:
-    """Phase router: one replica per device, then a one-device replay of
-    each replica's sub-trace; tokens must match."""
-    cfg = paged_config(base_cfg, replicas=len(devices),
-                       router_policy="round_robin", router_sync=True)
-    router = ReplicaRouter.build(init_params(cfg), cfg, batch=BATCH,
-                                 max_len=MAX_LEN, devices=devices)
-    trace = poisson_trace(ROUTER_REQUESTS, rate=2.0, prompt_len=ROUTER_PROMPT,
-                          gen_len=ROUTER_GEN, vocab=cfg.vocab,
-                          max_total=MAX_LEN, seed=0)
-    stats = router.run([q.fresh() for q in trace])
-    if stats.finished != len(trace):
-        fail(f"router completed {stats.finished}/{len(trace)} requests")
-    for i, sched in enumerate(router.replicas):
-        where = {d for x in jax.tree.leaves((sched.engine.params,
-                                             sched.allocator.cache))
-                 for d in x.devices()}
-        if where != {devices[i]}:
-            fail(f"replica {i} holds arrays on {where}, not {devices[i]}")
+    """Phase router: the launcher with one replica per device, then a
+    one-device replay of each replica's share of the trace; tokens must
+    match."""
+    stats = serve.main(ROUTER_ARGS)
+    if stats.replicas != len(devices):
+        fail(f"the router ran {stats.replicas} replicas, not {len(devices)}")
     say(f"router: {stats.finished} requests, {stats.generated_tokens} "
         f"tokens over {stats.replicas} replicas in {stats.router_steps} "
         f"router steps")
+    # Replica i's weights were on device i: each device's peak holds them.
+    wbytes = weight_bytes(base_cfg)
+    for d in devices:
+        peak = (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+        if peak < wbytes:
+            fail(f"{d} peaked at {peak} bytes, less than one replica's "
+                 f"{wbytes} bytes of weights")
 
-    # Replay on the first replica's engine (device 0), one sub-trace at a
-    # time, each on a fresh scheduler: same programs, same device kind.
-    engine0 = router.replicas[0].engine
-    for i, sched in enumerate(router.replicas):
-        sub = sorted(sched.requests.values(), key=lambda q: q.rid)
-        replay = ContinuousScheduler(engine0)
-        replay.run([q.fresh() for q in sub])
+    # Replay on device 0 (the default device), one share at a time, each on
+    # a fresh scheduler over one engine with the launcher's shapes.
+    cfg = paged_config(base_cfg, replicas=REPLICAS,
+                       router_policy="round_robin", router_sync=True)
+    engine = Engine(init_params(cfg), cfg, batch=BATCH,
+                    max_len=serve.workload_max_len(ROUTER_PROMPT, ROUTER_GEN))
+    for i, served in enumerate(stats.served):
+        replay = ContinuousScheduler(engine)
+        replay.run([q.fresh() for q in served])
         got = {q.rid: list(q.output) for q in replay.finished}
-        want = {q.rid: list(q.output) for q in sub}
+        want = {q.rid: list(q.output) for q in served}
         tokens = sum(len(v) for v in want.values())
-        say(f"router: replica {i} on {devices[i]}: {len(sub)} requests "
-            f"(arrivals {[q.arrival for q in sub]}), {tokens} tokens; "
+        say(f"router: replica {i} on {devices[i]}: {len(served)} requests "
+            f"(arrivals {[q.arrival for q in served]}), {tokens} tokens; "
             f"replay on {devices[0]}: "
             f"{'identical' if got == want else 'DIFFERENT'}")
         if got != want:
@@ -261,13 +273,14 @@ def main(argv=None) -> None:
     if devices[0].platform != "tpu":
         fail(f"JAX's first device is {devices[0].platform!r}, not a TPU; "
              f"this smoke test has no CPU fallback")
-    need = 4 if args.four_chips else 1
+    need = REPLICAS if args.four_chips else 1
     if len(devices) < need:
-        fail(f"--four-chips needs 4 TPU devices, JAX sees {len(devices)}")
+        fail(f"--four-chips needs {REPLICAS} TPU devices, JAX sees "
+             f"{len(devices)}")
 
     kind = devices[0].device_kind
     say(f"device: {kind} x {len(devices)}, compile cache "
-        f"{enable_compile_cache()}")
+        f"{enable_compile_cache() or 'off'}")
     cfg = get_config(ARCH, mux_n=MUX_N)
     say(f"{cfg.name}: {cfg.n_layers} layers, d {cfg.d_model}, "
         f"{cfg.n_heads} heads (kv {cfg.n_kv_heads}), d_ff {cfg.d_ff}, "
@@ -275,16 +288,13 @@ def main(argv=None) -> None:
         f"{weight_bytes(cfg)} bytes")
     clock = CompileClock()
 
-    phases = [("router", lambda: run_router(cfg, devices[:4]))] \
+    phases = [("router", lambda: run_router(cfg, devices[:REPLICAS]))] \
         if args.four_chips else [("serve", run_serve),
                                  ("kernels", lambda: run_kernels(cfg))]
     for name, run in phases:
         mark, t0 = clock.total, time.perf_counter()
         run()
         phase_report(name, clock, mark, t0)
-        # An Engine and its jitted bound methods form a reference cycle:
-        # collect it so the next phase's weights have the HBM.
-        gc.collect()
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": kind,
         "count": len(devices)}}), flush=True)

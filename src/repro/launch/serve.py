@@ -51,6 +51,7 @@ lives on device i mod the device count):
         --replicas 2 --router-policy least_loaded --report --gen 8
 """
 import argparse
+import contextlib
 import os
 import time
 
@@ -112,6 +113,12 @@ def init_params(cfg):
                                                      cfg)
 
 
+def workload_max_len(prompt_len: int, gen: int) -> int:
+    """Per-slot cache length of a ``--workload`` run: room for the longest
+    prompt and generation the Poisson trace draws."""
+    return prompt_len * 2 + gen * 4 + 1
+
+
 def _run_lockstep(args, cfg, mesh, mi, jax, Engine):
     key = jax.random.PRNGKey(0)
     params = init_params(cfg)
@@ -137,7 +144,7 @@ def _run_workload(args, cfg, mesh, mi, jax, Engine):
                                          static_batch_steps)
     params = init_params(cfg)
     n = max(cfg.mux.n, 1)
-    max_total = args.prompt_len * 2 + args.gen * 4 + 1
+    max_total = workload_max_len(args.prompt_len, args.gen)
     tracer = _make_tracer(args)
     with mesh:
         eng = Engine(params, cfg, batch=args.batch, max_len=max_total,
@@ -221,11 +228,16 @@ def _run_router(args, cfg, mesh, mi, jax, Engine):
     from repro.serving.scheduler import poisson_trace
     params = init_params(cfg)
     n = max(cfg.mux.n, 1)
-    max_total = args.prompt_len * 2 + args.gen * 4 + 1
+    max_total = workload_max_len(args.prompt_len, args.gen)
     tracer = _make_tracer(args)
-    placement = dict(mesh=mesh, mesh_info=mi) if args.mesh_shape \
-        else dict(devices=jax.devices())
-    with mesh:
+    if args.mesh_shape:
+        placement, scope = dict(mesh=mesh, mesh_info=mi), mesh
+    else:
+        # Each replica on its own device: no mesh, which would span
+        # device 0 only.
+        placement = dict(devices=jax.devices())
+        scope = contextlib.nullcontext()
+    with scope:
         router = ReplicaRouter.build(
             params, cfg, batch=args.batch, max_len=max_total,
             replicas=args.replicas, tracer=tracer, **placement)

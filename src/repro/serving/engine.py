@@ -31,6 +31,7 @@ prefix-protocol demuxers (``uses_prefix``) and stays None otherwise.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Optional
@@ -42,6 +43,7 @@ from repro.configs.base import ModelConfig
 from repro.models import Backbone
 from repro.nn.moe import SINGLE, MeshInfo
 from repro.serving.telemetry import NULL_TRACER
+from repro.serving.weakjit import weak_method
 
 
 @dataclasses.dataclass
@@ -56,8 +58,9 @@ class ServeState:
 
 
 class Engine:
-    """``device``: put the params on this one device, so every cache the
-    engine builds from them lives there too (one-device replicas behind
+    """``device``: put the params on this one device, and with them every
+    cache and page pool built for this engine (``placed``), so a scheduler
+    over it runs on that device alone (one-device replicas behind
     ``ReplicaRouter``); leave it None to keep the params where they are —
     and do not combine it with a ``mesh``."""
 
@@ -106,16 +109,17 @@ class Engine:
         # (width, batch), counted so telemetry can gauge compile pressure.
         self._variants: dict[tuple[int, int], "Engine"] = {}
         self.variant_compiles = 0
-        self._prefill = jax.jit(self._prefill_impl) if jit \
-            else self._prefill_impl
+        # Weakly bound (serving/weakjit.py): a dropped engine frees its
+        # weights at once instead of at the next cycle collection.
+        maybe_jit = jax.jit if jit else (lambda f, **kw: f)
+        self._prefill = maybe_jit(weak_method(self._prefill_impl))
         # Donate the cache: the decode step aliases the KV buffers instead of
         # allocating a second full cache every token (no-op on backends
         # without donation support, e.g. CPU — then it simply copies).
-        self._step = jax.jit(self._step_impl, donate_argnums=(2,)) if jit \
-            else self._step_impl
-        self._prime = jax.jit(self._prime_impl,
-                              static_argnames=("prime_len",)) if jit \
-            else self._prime_impl
+        self._step = maybe_jit(weak_method(self._step_impl),
+                               donate_argnums=(2,))
+        self._prime = maybe_jit(weak_method(self._prime_impl),
+                                static_argnames=("prime_len",))
 
     @staticmethod
     def _validate_serving_policy(cfg: ModelConfig) -> None:
@@ -214,13 +218,21 @@ class Engine:
             cache, index_embeds = self._prime(
                 self.params, prime_len=(p if compact else self.max_len))
         else:
-            with jax.default_device(self.device):
+            with self.placed():
                 cache = Backbone.init_cache(cfg, self.batch,
                                             1 if compact else self.max_len)
             index_embeds = None
         pos = jnp.full((self.batch,), p, jnp.int32)
         return ServeState(cache=cache, pos=pos, index_embeds=index_embeds,
                           cross_kv=cross_kv)
+
+    def placed(self):
+        """Context in which arrays built eagerly for this engine (caches,
+        page pools) land on its ``device``; a no-op without one.  Jitted
+        calls need none: they run where the params are."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
 
     def variant(self, width: int, batch: int) -> "Engine":
         """Width-class serving variant: an engine serving ``batch`` slots at

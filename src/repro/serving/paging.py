@@ -44,6 +44,7 @@ from repro.configs.base import ModelConfig
 from repro.nn.attention import paged_eligible
 from repro.serving.kvcache import _masked_restore
 from repro.serving.telemetry import NULL_TRACER
+from repro.serving.weakjit import weak_method
 
 # Cache pytree sections and the axis their *contiguous* leaves carry the
 # slot dimension on (paged pool leaves carry the pool on the same axis).
@@ -243,8 +244,10 @@ class PagedKVSlotAllocator:
             not p for flags in self._paged.values() for p in flags)
 
         self._jit = jit
-        maybe_jit = (lambda f, **kw: jax.jit(f, **kw)) if jit \
-            else (lambda f, **kw: f)
+        # Weakly bound (serving/weakjit.py): a dropped allocator frees its
+        # page pool at once instead of at the next cycle collection.
+        maybe_jit = (lambda f, **kw: jax.jit(weak_method(f), **kw)) if jit \
+            else (lambda f, **kw: weak_method(f))
         self._invalidate = maybe_jit(self._invalidate_impl,
                                      donate_argnums=(0,))
         self._reset = maybe_jit(self._reset_impl, donate_argnums=(0,))

@@ -51,7 +51,6 @@ import collections
 import dataclasses
 from typing import Callable, Optional, Sequence, TypeVar
 
-import jax
 import numpy as np
 
 from repro.serving.policies import SloClasses
@@ -252,6 +251,9 @@ class RouterStats:
     ttft_p99: float = -1.0
     per_class: dict = dataclasses.field(default_factory=dict)
     per_replica: list = dataclasses.field(default_factory=list)
+    # Per replica, the requests it finished (rid order, with their arrival
+    # steps and outputs): what a one-replica replay of its share needs.
+    served: list = dataclasses.field(default_factory=list)
 
     @property
     def tokens_per_step(self) -> float:
@@ -325,11 +327,9 @@ class ReplicaRouter:
                 c = ov if isinstance(ov, type(cfg)) \
                     else dataclasses.replace(cfg, serving=ov)
             dev = devices[i % len(devices)] if devices else None
-            # Caches built eagerly at construction land on ``dev`` too.
-            with jax.default_device(dev):
-                scheds.append(ContinuousScheduler(
-                    Engine(params, c, batch=batch, max_len=max_len,
-                           device=dev, **engine_kwargs)))
+            scheds.append(ContinuousScheduler(
+                Engine(params, c, batch=batch, max_len=max_len, device=dev,
+                       **engine_kwargs)))
         return cls(scheds, policy=policy, sync=sync, tracer=tracer)
 
     # -- queue ----------------------------------------------------------------
@@ -479,4 +479,6 @@ class ReplicaRouter:
                 "resumes": s.resumes,
                 "load": sched.load().as_dict(),
             })
+        st.served = [sorted(s.finished, key=lambda q: q.rid)
+                     for s in self.replicas]
         return st

@@ -405,13 +405,16 @@ class ContinuousScheduler:
         start = 0
         for i, (w, veng) in enumerate(zip(self.widths, engines)):
             primed = veng.prime(compact=self.paged)
-            if self.paged:
-                alloc = PagedKVSlotAllocator(
-                    veng.cfg, counts[i], veng.max_len, template=primed.cache,
-                    pool_pages=pools[i])
-            else:
-                alloc = KVSlotAllocator(
-                    veng.cfg, counts[i], veng.max_len, template=primed.cache)
+            # The cache / page pool lives on the engine's device, if any.
+            with veng.placed():
+                if self.paged:
+                    alloc = PagedKVSlotAllocator(
+                        veng.cfg, counts[i], veng.max_len,
+                        template=primed.cache, pool_pages=pools[i])
+                else:
+                    alloc = KVSlotAllocator(
+                        veng.cfg, counts[i], veng.max_len,
+                        template=primed.cache)
             self.classes.append(WidthClass(
                 index=i, width=w, start=start, n_slots=counts[i],
                 engine=veng, allocator=alloc,

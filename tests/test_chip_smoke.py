@@ -52,6 +52,19 @@ def test_compile_cache_dir(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", was)
 
 
+def test_compile_cache_stays_off_outside_a_checkout(monkeypatch, tmp_path):
+    """An installed copy (no pyproject.toml above ``src/repro``) has no
+    checkout to keep a cache in: nothing is written beside site-packages."""
+    was = jax.config.jax_compilation_cache_dir
+    installed = tmp_path / "lib" / "python3" / "site-packages" / "repro"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cache, "__file__",
+                        str(installed / "launch" / "cache.py"))
+    assert cache.checkout_root() is None
+    assert cache.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == was
+
+
 def test_peaks_are_keyed_by_device_kind():
     assert peaks.peaks(peaks.V5E).hbm_bytes == 819e9
     with pytest.raises(KeyError, match="no published peaks"):

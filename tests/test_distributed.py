@@ -95,6 +95,81 @@ def test_serve_launcher_on_emulated_mesh():
     assert "tok/s" in out.stdout
 
 
+def test_device_pinned_engine_keeps_its_scheduler_on_its_device():
+    """A scheduler built over ``Engine(device=d)`` anywhere (not only
+    behind the router) holds its weights, primed prefix and page pool on
+    d, and serves from there."""
+    _run_py(textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import dataclasses
+        import jax, numpy as np
+        from repro.configs.base import ServingConfig
+        from repro.configs.registry import get_smoke_config
+        from repro.models import Backbone
+        from repro.serving.engine import Engine
+        from repro.serving.scheduler import ContinuousScheduler, Request
+
+        cfg = get_smoke_config("qwen1.5-4b", mux_n=2)
+        cfg = dataclasses.replace(
+            cfg, serving=ServingConfig(paged=True, page_size=4))
+        params = Backbone.init(jax.random.PRNGKey(0), cfg)
+        dev = jax.devices()[2]
+        sched = ContinuousScheduler(
+            Engine(params, cfg, batch=2, max_len=16, device=dev))
+        held = (sched.engine.params, sched.allocator.cache,
+                sched.index_embeds)
+        where = {d for x in jax.tree.leaves(held) for d in x.devices()}
+        assert where == {dev}, where
+        rng = np.random.default_rng(0)
+        stats = sched.run([Request(
+            rid=i, max_new_tokens=3,
+            prompt=rng.integers(0, cfg.vocab, 3).astype(np.int32))
+            for i in range(3)])
+        assert stats.finished == 3
+        where = {d for x in jax.tree.leaves(sched.allocator.cache)
+                 for d in x.devices()}
+        assert where == {dev}, where
+        print("OK")
+    """))
+
+
+def test_serve_launcher_router_one_replica_per_device():
+    """``serve --replicas 4`` without --mesh-shape: one replica per device;
+    each replica's share of the trace, replayed alone on device 0, gives
+    the same tokens."""
+    _run_py(textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import dataclasses
+        import jax
+        from repro.configs.base import ServingConfig
+        from repro.configs.registry import get_smoke_config
+        from repro.launch import serve
+        from repro.serving.engine import Engine
+        from repro.serving.scheduler import ContinuousScheduler
+
+        stats = serve.main([
+            "--arch", "qwen1.5-4b", "--smoke", "--mux-n", "2",
+            "--workload", "poisson", "--paged", "--page-size", "4",
+            "--num-requests", "8", "--prompt-len", "4", "--gen", "4",
+            "--replicas", "4", "--router-sync"])
+        assert stats.finished == 8
+        assert [len(s) for s in stats.served] == [2, 2, 2, 2]
+        cfg = dataclasses.replace(
+            get_smoke_config("qwen1.5-4b", mux_n=2),
+            serving=ServingConfig(paged=True, page_size=4))
+        engine = Engine(serve.init_params(cfg), cfg, batch=2,
+                        max_len=serve.workload_max_len(4, 4))
+        for served in stats.served:
+            replay = ContinuousScheduler(engine)
+            replay.run([q.fresh() for q in served])
+            assert ({q.rid: q.output for q in replay.finished} ==
+                    {q.rid: q.output for q in served})
+        print("OK")
+    """))
+
+
 def test_prefix_pad_decode_matches_full(key):
     """prefix_pad model: decode-with-cache equals full forward."""
     import dataclasses

@@ -410,3 +410,28 @@ def test_kblock_config_validation_fails_fast():
         _cfg(paged=True, page_size=16, use_kernel=True, kblock_pages=1 << 16)
     # kernel off -> the knob is inert, any value constructs
     _cfg(paged=True, page_size=16, kblock_pages=1 << 16)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_dropped_engine_frees_its_buffers_without_gc(key, paged):
+    """Jitted engine and allocator methods hold their instance weakly:
+    dropping the last reference to a scheduler frees the weights and the
+    cache / page pool at once, with the cycle collector off — engines built
+    one after another in one process never pile up on the device."""
+    import gc
+    import weakref
+    cfg = _cfg(paged=paged, page_size=4) if paged else _cfg()
+    params = Backbone.init(key, cfg)
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        sched = ContinuousScheduler(Engine(params, cfg, batch=2, max_len=16))
+        assert sched.run(_requests([3, 2, 4, (2, 1)],
+                                   vocab=cfg.vocab)).finished == 4
+        weights = weakref.ref(jax.tree.leaves(sched.engine.params)[0])
+        cache = weakref.ref(jax.tree.leaves(sched.allocator.cache)[0])
+        del params, sched
+        assert weights() is None and cache() is None
+    finally:
+        if was:
+            gc.enable()
