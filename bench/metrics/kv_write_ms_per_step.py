@@ -1,0 +1,9 @@
+"""Kernels: per step of the traced window, the device time of the decode
+program's ops in scope ``kv_write`` (the writes of new K/V/pos into the
+page pool or cache): the union of their intervals inside the harness's
+step spans (``harness/scopes.py``)."""
+from harness import scopes
+
+
+def read(ctx):
+    return scopes.scope_ms(ctx, "kv_write")

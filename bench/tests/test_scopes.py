@@ -1,0 +1,138 @@
+"""The split of a traced window by the program's own spans and scopes
+(``harness/scopes.py`` and its readers), on a small trace recorded on one
+TPU v5e chip by ``make_layers_fixture.py``: twelve harness ``step`` spans,
+each one ``sched.step()`` of the tiny paged scheduler, and the decode
+program's optimized HLO."""
+from __future__ import annotations
+
+import types
+
+import pytest
+
+from conftest import BENCH
+from harness import scopes, trace
+
+DATA = BENCH / "tests" / "data"
+XPLANE = DATA / "layers.xplane.pb"
+HLO = DATA / "layers.hlo.txt"
+STEPS = 12
+# 2 slots x 4 lanes x 256 vocabulary, bf16 logits
+LOGITS_BYTES = 2 * 4 * 256 * 2
+HOST = ("host_admit_ms", "host_feed_ms", "host_readback_ms",
+        "host_sample_ms", "host_release_ms")
+DEVICE = ("mux_ms_per_step", "attention_ms_per_step", "kv_write_ms_per_step",
+          "mlp_ms_per_step", "demux_ms_per_step", "lm_head_ms_per_step",
+          "unscoped_ms_per_step", "page_programs_ms_per_step")
+
+
+def reader(name):
+    import run
+    return run.load_reader(name)
+
+
+@pytest.fixture(scope="module")
+def ctx():
+    tr = trace.load(str(XPLANE))
+    red = trace.reduce(tr)
+    lay = scopes.load(str(XPLANE), HLO.read_text(), tr)
+    return types.SimpleNamespace(trace=red, layers=lay)
+
+
+def test_scope_map_reads_the_innermost_scope():
+    """The innermost scope on an op's path; none on the path reads
+    unscoped; an op with no ``op_name`` takes its container's scope
+    (``gather_body``'s ops belong to the attention gather loop), and
+    containers are dropped."""
+    text = "\n".join([
+        "HloModule jit__step_impl, is_scheduled=true",
+        "",
+        "%gather_body (p.1: (s32[], bf16[8])) -> (s32[], bf16[8]) {",
+        "  %p.1 = (s32[], bf16[8]{0}) parameter(0)",
+        "  %dynamic-update-slice.7 = bf16[8]{0} dynamic-update-slice(%a, %b)",
+        "  ROOT %tuple.8 = (s32[], bf16[8]{0}) tuple(%i, %dynamic-update-"
+        "slice.7)",
+        "}",
+        "",
+        "ENTRY %main.9 (p: bf16[8]) -> bf16[8] {",
+        '  %fusion.1 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f, '
+        'metadata={op_name="jit(_step_impl)/while/body/attention/'
+        'kv_write/scatter"}',
+        '  %dot.2 = bf16[8]{0} dot(%a, %b), metadata={op_name="jit(_step_'
+        'impl)/while/body/attention/dot_general"}',
+        "  %while.3 = (s32[], bf16[8]{0}) while(%t), condition=%c, "
+        'body=%gather_body, metadata={op_name="jit(_step_impl)/attention/'
+        'gather"}',
+        "  %copy.4 = bf16[8]{0} copy(%x)",
+        '  %custom-call.5 = bf16[8]{0} custom-call(%x), custom_call_target='
+        '"f", metadata={op_name="jit(_step_impl)/lm_head/dot_general"}',
+        '  ROOT %dynamic-slice.6 = bf16[1]{0} dynamic-slice(%x, %i), '
+        'metadata={op_name="jit(_step_impl)/while/body/dynamic_slice"}',
+        "}",
+    ])
+    assert scopes.scope_map(text) == {
+        "p.1": "attention", "dynamic-update-slice.7": "attention",
+        "tuple.8": "attention", "fusion.1": "kv_write", "dot.2": "attention",
+        "while.3": None, "copy.4": scopes.UNSCOPED, "custom-call.5": "lm_head",
+        "dynamic-slice.6": scopes.UNSCOPED}
+
+
+def test_fixture_spans_nest_in_the_steps(ctx):
+    red, lay = ctx.trace, ctx.layers
+    assert len(red.steps) == STEPS
+    for lo, hi in red.steps:
+        inside = sorted((a, n, b) for n, a, b, _ in lay.spans
+                        if lo <= a and b <= hi)
+        assert [n for _, n, _ in inside] == list(scopes.HOST_SPANS)
+        assert all(b <= a2 for (_, _, b), (a2, _, _)
+                   in zip(inside, inside[1:]))
+    assert all(st == {"bytes": LOGITS_BYTES} for n, *_, st in lay.spans
+               if n == "sched.readback")
+
+
+def test_fixture_programs_and_names(ctx):
+    """Every op of the decode program in the trace is named in the HLO
+    text, each scope holds some of them, and a page program ran inside
+    the window."""
+    progs = {p for evs in ctx.layers.ops.values() for _, p, _, _ in evs}
+    assert scopes.STEP_PROGRAM in progs
+    assert progs & set(scopes.PAGE_PROGRAMS)
+    step_ops = {name for evs in ctx.layers.ops.values()
+                for name, p, _, _ in evs if p == scopes.STEP_PROGRAM}
+    assert step_ops <= set(ctx.layers.scope_of)
+    for scope in scopes.SCOPES:
+        assert scope in {ctx.layers.scope_of[n] for n in step_ops}, scope
+
+
+@pytest.mark.parametrize("name", HOST + DEVICE + ("readback_bytes_per_step",))
+def test_every_new_reader_reads_the_fixture(ctx, name):
+    value = reader(name)(ctx)
+    assert value is not None and value > 0, name
+    if name == "readback_bytes_per_step":
+        assert value == LOGITS_BYTES
+
+
+def test_host_split_closes_on_host_ms_per_step(ctx):
+    parts = sum(reader(n)(ctx) for n in HOST)
+    whole = reader("host_ms_per_step")(ctx)
+    assert parts == pytest.approx(whole, rel=0.05)
+    assert parts <= whole
+
+
+def test_device_split_closes_on_device_ms_per_step(ctx):
+    parts = sum(reader(n)(ctx) for n in DEVICE)
+    whole = reader("device_ms_per_step")(ctx)
+    assert parts == pytest.approx(whole, rel=0.05)
+
+
+def test_a_program_without_spans_leaves_the_metrics_out():
+    """On the trace of a program that opens no ``sched.*`` span and a run
+    that kept no HLO text (``steps.xplane.pb``), every new reader gives
+    None, so the metric is left out of the run's line."""
+    path = str(BENCH / "tests" / "data" / "steps.xplane.pb")
+    tr = trace.load(path)
+    old = types.SimpleNamespace(trace=trace.reduce(tr),
+                                layers=scopes.load(path, "", tr))
+    for name in HOST + DEVICE[:-1] + ("readback_bytes_per_step",):
+        assert reader(name)(old) is None, name
+    assert reader("host_admit_ms")(types.SimpleNamespace(
+        trace=old.trace)) is None

@@ -363,9 +363,11 @@ def main(argv=None):
                          "instead of skipping idle replicas")
     # telemetry (serving/telemetry.py)
     ap.add_argument("--trace", default="", metavar="OUT.trace.json",
-                    help="record request-lifecycle spans + per-step "
-                         "timeline and write a Chrome/Perfetto traceEvents "
-                         "JSON (load at https://ui.perfetto.dev)")
+                    help="record request-lifecycle events, the "
+                         "scheduler's host spans (sched.admit/feed/"
+                         "readback/sample/release) and per-slot steps on "
+                         "the wall clock, and write a Chrome/Perfetto "
+                         "traceEvents JSON (load at https://ui.perfetto.dev)")
     ap.add_argument("--metrics", default="", metavar="OUT.jsonl",
                     help="write one metrics snapshot per step as JSONL "
                          "(counters + gauges, r{i}/- or router/-prefixed)")

@@ -116,26 +116,34 @@ def _layer_apply(p, x, cfg: ModelConfig, kind: dict, *, positions,
             f"chunked decode (serving.prefill_chunk > 1) is not supported "
             f"for {mixer!r} mixers — xLSTM state updates have no row-masked "
             f"form yet; set prefill_chunk=1 for xLSTM archs")
-    h = norm.apply(p["norm1"], x)
-    if mixer == "attn":
-        out, new_cache = Attention.apply(
-            p["attn"], h, cfg.attn_config(window=kind["window"]),
-            positions=positions, cache=cache, cache_index=cache_index,
-            block_table=block_table, chunk_lens=chunk_lens)
-    elif mixer == "mla":
-        out, new_cache = MLA.apply(p["attn"], h, cfg.mla, positions=positions,
-                                   cache=cache, cache_index=cache_index,
-                                   block_table=block_table,
-                                   chunk_lens=chunk_lens)
-    elif mixer == "mamba":
-        out, new_cache = Mamba.apply(p["mamba"], h, cfg.mamba, cache=cache,
-                                     chunk_lens=chunk_lens)
-    elif mixer == "mlstm":
-        out, new_cache = MLSTM.apply(p["mlstm"], h, cfg.xlstm, cache=cache)
-    elif mixer == "slstm":
-        out, new_cache = SLSTM.apply(p["slstm"], h, cfg.xlstm, cache=cache)
-    else:
-        raise ValueError(mixer)
+    # Named scopes tag the device ops of each part of a layer in the
+    # compiled program's metadata (``attention`` for both attention
+    # mixers, the mixer's own name otherwise; ``kv_write`` nests inside
+    # ``nn/attention.py``); they change no numerics.
+    with jax.named_scope("attention" if mixer in ("attn", "mla") else mixer):
+        h = norm.apply(p["norm1"], x)
+        if mixer == "attn":
+            out, new_cache = Attention.apply(
+                p["attn"], h, cfg.attn_config(window=kind["window"]),
+                positions=positions, cache=cache, cache_index=cache_index,
+                block_table=block_table, chunk_lens=chunk_lens)
+        elif mixer == "mla":
+            out, new_cache = MLA.apply(p["attn"], h, cfg.mla,
+                                       positions=positions, cache=cache,
+                                       cache_index=cache_index,
+                                       block_table=block_table,
+                                       chunk_lens=chunk_lens)
+        elif mixer == "mamba":
+            out, new_cache = Mamba.apply(p["mamba"], h, cfg.mamba,
+                                         cache=cache, chunk_lens=chunk_lens)
+        elif mixer == "mlstm":
+            out, new_cache = MLSTM.apply(p["mlstm"], h, cfg.xlstm,
+                                         cache=cache)
+        elif mixer == "slstm":
+            out, new_cache = SLSTM.apply(p["slstm"], h, cfg.xlstm,
+                                         cache=cache)
+        else:
+            raise ValueError(mixer)
     x = x + out
 
     if kind["cross"]:
@@ -145,12 +153,15 @@ def _layer_apply(p, x, cfg: ModelConfig, kind: dict, *, positions,
         x = x + jnp.tanh(p["cross_gate"].astype(x.dtype)) * out
 
     if kind["mlp"] == "dense":
-        h = norm.apply(p["norm2"], x)
-        x = x + MLP.apply(p["mlp"], h, activation=cfg.activation)
+        with jax.named_scope("mlp"):
+            h = norm.apply(p["norm2"], x)
+            out = MLP.apply(p["mlp"], h, activation=cfg.activation)
+        x = x + out
     elif kind["mlp"] == "moe":
-        h = norm.apply(p["norm2"], x)
-        out, aux = MoE.apply(p["moe"], h, cfg.moe, mesh_info, mesh=mesh,
-                             row_mask=row_mask)
+        with jax.named_scope("mlp"):
+            h = norm.apply(p["norm2"], x)
+            out, aux = MoE.apply(p["moe"], h, cfg.moe, mesh_info, mesh=mesh,
+                                 row_mask=row_mask)
         x = x + out
     return x, new_cache, aux
 
@@ -389,7 +400,8 @@ class Backbone:
             if new_cache is not None:
                 new_cache["tail"].append(nc)
 
-        x = make_norm(cfg.norm).apply(params["final_norm"], x)
+        with jax.named_scope("lm_head"):
+            x = make_norm(cfg.norm).apply(params["final_norm"], x)
         return x, new_cache, aux_total
 
     # -- embedding / logits ----------------------------------------------------------
@@ -524,18 +536,20 @@ class Backbone:
                 index_embeds=index_embeds, cross_kv=cross_kv,
                 lane_mask=lane_mask, block_table=block_table, mesh=mesh,
                 mesh_info=mesh_info)
-        if mux.active:
-            b, n = tokens.shape
-            emb = Backbone.embed(params, tokens[:, :, None], cfg)  # (B,N,1,d)
-            if lane_mask is not None:
-                emb = emb * lane_mask[:, :, None, None].astype(emb.dtype)
-            x = get_mux(mux.strategy).apply(params["mux"], emb,
-                                            mux)                  # (B,1,d)
-        else:
-            b = tokens.shape[0]
-            x = Backbone.embed(params, tokens[:, None], cfg)       # (B,1,d)
-            if lane_mask is not None:
-                x = x * lane_mask[:, :1, None].astype(x.dtype)
+        with jax.named_scope("mux"):
+            if mux.active:
+                b, n = tokens.shape
+                emb = Backbone.embed(params, tokens[:, :, None],
+                                     cfg)                         # (B,N,1,d)
+                if lane_mask is not None:
+                    emb = emb * lane_mask[:, :, None, None].astype(emb.dtype)
+                x = get_mux(mux.strategy).apply(params["mux"], emb,
+                                                mux)              # (B,1,d)
+            else:
+                b = tokens.shape[0]
+                x = Backbone.embed(params, tokens[:, None], cfg)   # (B,1,d)
+                if lane_mask is not None:
+                    x = x * lane_mask[:, :1, None].astype(x.dtype)
 
         positions = jnp.broadcast_to(
             ci[:, None] if ci.ndim else ci, (b, 1))
@@ -552,16 +566,20 @@ class Backbone:
             row_mask=row_mask, mesh=mesh, mesh_info=mesh_info)
 
         if mux.active:
-            demuxed = _demux_decode(params, h, cfg, index_embeds)
-            logits = Backbone.logits(params, demuxed[:, :, 0], cfg)  # (B,N,V)
-            if lane_mask is not None:
-                logits = jnp.where(lane_mask[:, :, None].astype(bool),
-                                   logits, 0.0)
+            with jax.named_scope("demux"):
+                demuxed = _demux_decode(params, h, cfg, index_embeds)
+            with jax.named_scope("lm_head"):
+                logits = Backbone.logits(params, demuxed[:, :, 0],
+                                         cfg)                     # (B,N,V)
+                if lane_mask is not None:
+                    logits = jnp.where(lane_mask[:, :, None].astype(bool),
+                                       logits, 0.0)
         else:
-            logits = Backbone.logits(params, h[:, 0], cfg)           # (B,V)
-            if lane_mask is not None:
-                logits = jnp.where(lane_mask[:, :1].astype(bool),
-                                   logits, 0.0)
+            with jax.named_scope("lm_head"):
+                logits = Backbone.logits(params, h[:, 0], cfg)    # (B,V)
+                if lane_mask is not None:
+                    logits = jnp.where(lane_mask[:, :1].astype(bool),
+                                       logits, 0.0)
         return logits, new_cache
 
     @staticmethod
@@ -572,18 +590,19 @@ class Backbone:
         """Chunked-prefill decode step (see ``decode_step``): a (B, ·, C)
         token chunk advances slot b by ``chunk_lens[b]`` positions."""
         mux = cfg.mux
-        if mux.active:
-            b, n, c = tokens.shape
-            emb = Backbone.embed(params, tokens, cfg)          # (B,N,C,d)
-            if lane_mask is not None:
-                emb = emb * lane_mask[..., None].astype(emb.dtype)
-            x = get_mux(mux.strategy).apply(params["mux"], emb,
-                                            mux)               # (B,C,d)
-        else:
-            b, c = tokens.shape
-            x = Backbone.embed(params, tokens, cfg)            # (B,C,d)
-            if lane_mask is not None:
-                x = x * lane_mask[:, 0, :, None].astype(x.dtype)
+        with jax.named_scope("mux"):
+            if mux.active:
+                b, n, c = tokens.shape
+                emb = Backbone.embed(params, tokens, cfg)      # (B,N,C,d)
+                if lane_mask is not None:
+                    emb = emb * lane_mask[..., None].astype(emb.dtype)
+                x = get_mux(mux.strategy).apply(params["mux"], emb,
+                                                mux)           # (B,C,d)
+            else:
+                b, c = tokens.shape
+                x = Backbone.embed(params, tokens, cfg)        # (B,C,d)
+                if lane_mask is not None:
+                    x = x * lane_mask[:, 0, :, None].astype(x.dtype)
 
         positions = ci[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         # Row validity for row-exact MoE dispatch: rows at or past a slot's
@@ -601,14 +620,17 @@ class Backbone:
             mesh_info=mesh_info)
 
         if mux.active:
-            demuxed = _demux_decode(params, h, cfg, index_embeds)
-            logits = Backbone.logits(params, demuxed, cfg)     # (B,N,C,V)
-            if lane_mask is not None:
-                logits = jnp.where(lane_mask[..., None].astype(bool),
-                                   logits, 0.0)
+            with jax.named_scope("demux"):
+                demuxed = _demux_decode(params, h, cfg, index_embeds)
+            with jax.named_scope("lm_head"):
+                logits = Backbone.logits(params, demuxed, cfg)  # (B,N,C,V)
+                if lane_mask is not None:
+                    logits = jnp.where(lane_mask[..., None].astype(bool),
+                                       logits, 0.0)
         else:
-            logits = Backbone.logits(params, h, cfg)           # (B,C,V)
-            if lane_mask is not None:
-                logits = jnp.where(lane_mask[:, 0, :, None].astype(bool),
-                                   logits, 0.0)
+            with jax.named_scope("lm_head"):
+                logits = Backbone.logits(params, h, cfg)        # (B,C,V)
+                if lane_mask is not None:
+                    logits = jnp.where(
+                        lane_mask[:, 0, :, None].astype(bool), logits, 0.0)
         return logits, new_cache

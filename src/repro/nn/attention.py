@@ -172,17 +172,18 @@ def masked_chunk_write(cache, idx, row_ok, values: dict, pos_q):
     no-op; ``idx`` rows are distinct because C <= S, so the scatter is
     deterministic).  ``pos`` is merged the same way from ``pos_q``.
     """
-    b = idx.shape[0]
-    rows = jnp.arange(b)[:, None]
-    out = {}
-    for key, new in values.items():
-        old = cache[key][rows, idx]
-        keep = row_ok.reshape(row_ok.shape + (1,) * (new.ndim - 2))
-        out[key] = cache[key].at[rows, idx].set(
-            jnp.where(keep, new.astype(cache[key].dtype), old))
-    p_new = jnp.where(row_ok, pos_q, cache["pos"][rows, idx])
-    out["pos"] = cache["pos"].at[rows, idx].set(p_new)
-    return out
+    with jax.named_scope("kv_write"):
+        b = idx.shape[0]
+        rows = jnp.arange(b)[:, None]
+        out = {}
+        for key, new in values.items():
+            old = cache[key][rows, idx]
+            keep = row_ok.reshape(row_ok.shape + (1,) * (new.ndim - 2))
+            out[key] = cache[key].at[rows, idx].set(
+                jnp.where(keep, new.astype(cache[key].dtype), old))
+        p_new = jnp.where(row_ok, pos_q, cache["pos"][rows, idx])
+        out["pos"] = cache["pos"].at[rows, idx].set(p_new)
+        return out
 
 
 def make_attention_mask(q_pos, k_pos, *, causal: bool, window: Optional[int],
@@ -365,12 +366,13 @@ class Attention:
             page_ids = jnp.maximum(block_table[rows, page_idx], 0)
             off = ci_v % ps
             pos_q = jnp.broadcast_to(positions, (b, 1))
-            k_pages = cache["k_pages"].at[page_ids, off].set(
-                k[:, 0].astype(cache["k_pages"].dtype))
-            v_pages = cache["v_pages"].at[page_ids, off].set(
-                v[:, 0].astype(cache["v_pages"].dtype))
-            pos_pages = cache["pos"].at[page_ids, off].set(
-                pos_q[:, 0].astype(jnp.int32))
+            with jax.named_scope("kv_write"):
+                k_pages = cache["k_pages"].at[page_ids, off].set(
+                    k[:, 0].astype(cache["k_pages"].dtype))
+                v_pages = cache["v_pages"].at[page_ids, off].set(
+                    v[:, 0].astype(cache["v_pages"].dtype))
+                pos_pages = cache["pos"].at[page_ids, off].set(
+                    pos_q[:, 0].astype(jnp.int32))
             new_cache = {"k_pages": k_pages, "v_pages": v_pages,
                          "pos": pos_pages}
             from repro.kernels.paged_attention import ops as paged_ops
@@ -381,27 +383,31 @@ class Attention:
         else:
             slots = cache["k"].shape[1]
             ci = jnp.asarray(cache_index, jnp.int32)
-            if ci.ndim:
-                # Per-slot positions (B,): each batch row writes its own slot.
-                rows = jnp.arange(b)
-                slot = (ci % slots).astype(jnp.int32)
-                k_cache = cache["k"].at[rows, slot].set(
-                    k[:, 0].astype(cache["k"].dtype))
-                v_cache = cache["v"].at[rows, slot].set(
-                    v[:, 0].astype(cache["v"].dtype))
-                pos = cache["pos"].at[rows, slot].set(
-                    jnp.broadcast_to(positions, (b, 1))[:, 0]
-                    .astype(jnp.int32))
-            else:
-                slot = (ci % slots).astype(jnp.int32)
-                k_cache = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-                pos = jax.lax.dynamic_update_slice(
-                    cache["pos"],
-                    jnp.broadcast_to(positions, (b, 1)).astype(jnp.int32),
-                    (0, slot))
+            with jax.named_scope("kv_write"):
+                if ci.ndim:
+                    # Per-slot positions (B,): each batch row writes its own
+                    # slot.
+                    rows = jnp.arange(b)
+                    slot = (ci % slots).astype(jnp.int32)
+                    k_cache = cache["k"].at[rows, slot].set(
+                        k[:, 0].astype(cache["k"].dtype))
+                    v_cache = cache["v"].at[rows, slot].set(
+                        v[:, 0].astype(cache["v"].dtype))
+                    pos = cache["pos"].at[rows, slot].set(
+                        jnp.broadcast_to(positions, (b, 1))[:, 0]
+                        .astype(jnp.int32))
+                else:
+                    slot = (ci % slots).astype(jnp.int32)
+                    k_cache = jax.lax.dynamic_update_slice(
+                        cache["k"], k.astype(cache["k"].dtype),
+                        (0, slot, 0, 0))
+                    v_cache = jax.lax.dynamic_update_slice(
+                        cache["v"], v.astype(cache["v"].dtype),
+                        (0, slot, 0, 0))
+                    pos = jax.lax.dynamic_update_slice(
+                        cache["pos"],
+                        jnp.broadcast_to(positions, (b, 1)).astype(jnp.int32),
+                        (0, slot))
             new_cache = {"k": k_cache, "v": v_cache, "pos": pos}
             mask = make_attention_mask(
                 jnp.broadcast_to(positions, (b, 1)), pos, causal=cfg.causal,
@@ -443,12 +449,13 @@ class Attention:
             page_ids = jnp.maximum(block_table[rows, page_idx], 0)
             page_ids = jnp.where(row_ok, page_ids, 0)   # invalid rows: trash
             off = pos_q % ps
-            k_pages = cache["k_pages"].at[page_ids, off].set(
-                k.astype(cache["k_pages"].dtype))
-            v_pages = cache["v_pages"].at[page_ids, off].set(
-                v.astype(cache["v_pages"].dtype))
-            pos_pages = cache["pos"].at[page_ids, off].set(
-                jnp.where(row_ok, pos_q, -1))
+            with jax.named_scope("kv_write"):
+                k_pages = cache["k_pages"].at[page_ids, off].set(
+                    k.astype(cache["k_pages"].dtype))
+                v_pages = cache["v_pages"].at[page_ids, off].set(
+                    v.astype(cache["v_pages"].dtype))
+                pos_pages = cache["pos"].at[page_ids, off].set(
+                    jnp.where(row_ok, pos_q, -1))
             new_cache = {"k_pages": k_pages, "v_pages": v_pages,
                          "pos": pos_pages}
             from repro.kernels.paged_attention import ops as paged_ops
@@ -688,14 +695,16 @@ class MLA:
                 page_ids = jnp.maximum(block_table[rows, page_idx], 0)
                 page_ids = jnp.where(row_ok, page_ids, 0)  # invalid: trash
                 off = pos_q % ps
-                new_cache = {
-                    "ckv_pages": cache["ckv_pages"].at[page_ids, off].set(
-                        ckv.astype(cache["ckv_pages"].dtype)),
-                    "krope_pages": cache["krope_pages"].at[page_ids, off].set(
-                        krope.astype(cache["krope_pages"].dtype)),
-                    "pos": cache["pos"].at[page_ids, off].set(
-                        jnp.where(row_ok, pos_q, -1)),
-                }
+                with jax.named_scope("kv_write"):
+                    new_cache = {
+                        "ckv_pages": cache["ckv_pages"].at[page_ids, off].set(
+                            ckv.astype(cache["ckv_pages"].dtype)),
+                        "krope_pages":
+                            cache["krope_pages"].at[page_ids, off].set(
+                                krope.astype(cache["krope_pages"].dtype)),
+                        "pos": cache["pos"].at[page_ids, off].set(
+                            jnp.where(row_ok, pos_q, -1)),
+                    }
                 ckv_g, krope_g, pos_g = MLA._gather_paged_latents(
                     new_cache, block_table)
                 out = MLA._absorbed_attention(
@@ -750,14 +759,15 @@ class MLA:
             page_ids = jnp.maximum(block_table[rows, page_idx], 0)
             off = ci_v % ps
             pos_q = jnp.broadcast_to(positions, (b, 1))
-            new_cache = {
-                "ckv_pages": cache["ckv_pages"].at[page_ids, off].set(
-                    ckv[:, 0].astype(cache["ckv_pages"].dtype)),
-                "krope_pages": cache["krope_pages"].at[page_ids, off].set(
-                    krope[:, 0].astype(cache["krope_pages"].dtype)),
-                "pos": cache["pos"].at[page_ids, off].set(
-                    pos_q[:, 0].astype(jnp.int32)),
-            }
+            with jax.named_scope("kv_write"):
+                new_cache = {
+                    "ckv_pages": cache["ckv_pages"].at[page_ids, off].set(
+                        ckv[:, 0].astype(cache["ckv_pages"].dtype)),
+                    "krope_pages": cache["krope_pages"].at[page_ids, off].set(
+                        krope[:, 0].astype(cache["krope_pages"].dtype)),
+                    "pos": cache["pos"].at[page_ids, off].set(
+                        pos_q[:, 0].astype(jnp.int32)),
+                }
             ckv_g, krope_g, pos_g = MLA._gather_paged_latents(
                 new_cache, block_table)
             out = MLA._absorbed_attention(
@@ -767,27 +777,28 @@ class MLA:
             # computed entirely in the compressed latent space, so the cache is
             # never expanded to per-head K/V (that would be O(S*H*d) bytes).
             ci = jnp.asarray(cache_index, jnp.int32)
-            if ci.ndim:
-                # Per-slot positions (B,): per-row latent-cache writes.
-                rows = jnp.arange(b)
-                ckv_c = cache["ckv"].at[rows, ci].set(
-                    ckv[:, 0].astype(cache["ckv"].dtype))
-                krope_c = cache["krope"].at[rows, ci].set(
-                    krope[:, 0].astype(cache["krope"].dtype))
-                pos = cache["pos"].at[rows, ci].set(
-                    jnp.broadcast_to(positions, (b, 1))[:, 0]
-                    .astype(jnp.int32))
-            else:
-                ckv_c = jax.lax.dynamic_update_slice(
-                    cache["ckv"], ckv.astype(cache["ckv"].dtype),
-                    (0, ci, 0))
-                krope_c = jax.lax.dynamic_update_slice(
-                    cache["krope"], krope.astype(cache["krope"].dtype),
-                    (0, ci, 0))
-                pos = jax.lax.dynamic_update_slice(
-                    cache["pos"],
-                    jnp.broadcast_to(positions, (b, 1)).astype(jnp.int32),
-                    (0, ci))
+            with jax.named_scope("kv_write"):
+                if ci.ndim:
+                    # Per-slot positions (B,): per-row latent-cache writes.
+                    rows = jnp.arange(b)
+                    ckv_c = cache["ckv"].at[rows, ci].set(
+                        ckv[:, 0].astype(cache["ckv"].dtype))
+                    krope_c = cache["krope"].at[rows, ci].set(
+                        krope[:, 0].astype(cache["krope"].dtype))
+                    pos = cache["pos"].at[rows, ci].set(
+                        jnp.broadcast_to(positions, (b, 1))[:, 0]
+                        .astype(jnp.int32))
+                else:
+                    ckv_c = jax.lax.dynamic_update_slice(
+                        cache["ckv"], ckv.astype(cache["ckv"].dtype),
+                        (0, ci, 0))
+                    krope_c = jax.lax.dynamic_update_slice(
+                        cache["krope"], krope.astype(cache["krope"].dtype),
+                        (0, ci, 0))
+                    pos = jax.lax.dynamic_update_slice(
+                        cache["pos"],
+                        jnp.broadcast_to(positions, (b, 1)).astype(jnp.int32),
+                        (0, ci))
             new_cache = {"ckv": ckv_c, "krope": krope_c, "pos": pos}
             out = MLA._absorbed_attention(
                 params, q, ckv_c, krope_c, pos,

@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Any, Optional
 
 import jax
@@ -42,7 +41,6 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import Backbone
 from repro.nn.moe import SINGLE, MeshInfo
-from repro.serving.telemetry import NULL_TRACER
 from repro.serving.weakjit import weak_method
 
 
@@ -78,10 +76,6 @@ class Engine:
         self.max_len = max_len + cfg.mux.prefix_len
         self.mesh = mesh
         self.mesh_info = mesh_info
-        # Telemetry recorder (serving/telemetry.py); the scheduler's
-        # ``set_tracer`` rebinds it.  The no-op default keeps the untraced
-        # step path byte-identical.
-        self.tracer = NULL_TRACER
         chunk = cfg.serving.prefill_chunk
         if chunk > 1:
             # Chunked decode needs per-row write validity: attention caches
@@ -277,7 +271,6 @@ class Engine:
         eng = Engine(params, vcfg, batch=batch, max_len=serve_len,
                      mesh=self.mesh, mesh_info=self.mesh_info, jit=self._jit,
                      device=self.device)
-        eng.tracer = self.tracer
         return eng
 
     def step(self, state: ServeState, tokens, lane_mask=None,
@@ -297,17 +290,10 @@ class Engine:
             lane_mask = jnp.asarray(lane_mask)
         if chunk_lens is not None:
             chunk_lens = jnp.asarray(chunk_lens, jnp.int32)
-        t0 = time.perf_counter() if self.tracer.enabled else 0.0
         logits, cache = self._step(self.params, jnp.asarray(tokens),
                                    state.cache, state.pos,
                                    state.index_embeds, state.cross_kv,
                                    lane_mask, block_table, chunk_lens)
-        if self.tracer.enabled:
-            # Host wall-clock of the step *dispatch* (async under jax — a
-            # block_until_ready here would serialise the pipeline telemetry
-            # exists to observe, so this deliberately excludes device wait).
-            self.tracer.event("engine_step",
-                              wall_ms=(time.perf_counter() - t0) * 1e3)
         advance = 1 if chunk_lens is None else chunk_lens
         return logits, dataclasses.replace(state, cache=cache,
                                            pos=state.pos + advance)

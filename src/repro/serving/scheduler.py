@@ -79,7 +79,7 @@ from repro.serving.kvcache import KVSlotAllocator
 from repro.serving.paging import PagedKVSlotAllocator, pages_for
 from repro.serving.policies import SloClasses
 from repro.serving.slots import FREE, ParkedGroup, SlotTable, SwapLedger
-from repro.serving.telemetry import as_scope, kblock_stats
+from repro.serving.telemetry import as_scope
 
 
 @dataclasses.dataclass
@@ -500,15 +500,12 @@ class ContinuousScheduler:
 
     def set_tracer(self, tracer) -> None:
         """Attach a telemetry recorder (``serving/telemetry.py``) to this
-        scheduler and everything it owns — engines, allocators, swap
-        ledger.  ``tracer`` may be a ``Tracer`` (bound to replica scope 0),
-        an existing scope (a router hands each replica its own), or None
-        (the ``NULL_TRACER`` no-op default: the untraced path is
-        untouched)."""
+        scheduler and everything it owns — allocators, swap ledger.
+        ``tracer`` may be a ``Tracer`` (bound to replica scope 0), an
+        existing scope (a router hands each replica its own), or None (the
+        ``NULL_TRACER`` no-op default: the untraced path is untouched)."""
         self.tracer = as_scope(tracer)
-        self.engine.tracer = self.tracer
         for c in self.classes:
-            c.engine.tracer = self.tracer
             c.allocator.tracer = self.tracer
         self.ledger.tracer = self.tracer
 
@@ -1028,77 +1025,98 @@ class ContinuousScheduler:
 
     def step(self) -> None:
         """Admit, run one jitted decode step for all B slots, then ramp /
-        sample / retire per lane."""
+        sample / retire per lane.
+
+        Five host spans (``Tracer.span``; profiler annotations) split the
+        step, each entered once and in this order: ``sched.admit``,
+        ``sched.feed`` (token grid, page mapping, the engine dispatch),
+        ``sched.readback`` (the logits' device-to-host copy, its ``bytes``
+        stat counting them), ``sched.sample`` and ``sched.release``."""
         self.tracer.now = self.t
-        self._admit()
+        with self.tracer.span("sched.admit"):
+            self._admit()
         if self.chunk > 1:
             mask, released, advance = self._run_chunked_step()
         else:
             mask, released, advance = self._run_single_step()
-        self._finish_step(mask, released, advance)
+        with self.tracer.span("sched.release"):
+            self._finish_step(mask, released, advance)
+
+    def _read_back(self, launched: list) -> list:
+        """Copy each launched class's logits to the host, as
+        ``logits_by_class`` (None for a class that did not launch); a
+        class without mux gains its lane axis."""
+        out: list = [None] * len(self.classes)
+        with self.tracer.span("sched.readback",
+                              bytes=sum(lg.nbytes for _, lg in launched)):
+            for c, logits in launched:
+                host = np.asarray(logits)
+                out[c.index] = host if c.mux_active else host[:, None]
+        return out
 
     def _run_single_step(self):
         """Legacy one-token step: every live lane feeds exactly one token
         (prompt ramp or last output) and every slot advances one position —
         the ``prefill_chunk == 1`` path, bit-for-bit the original engine."""
-        mask = self.table.lane_mask()                    # (B, N_max)
-        tokens = np.zeros((self.n_slots, self.n_lanes), np.int32)
-        for s in range(self.n_slots):
-            for l in range(self.n_lanes):
-                rid = int(self.table.grid[s, l])
-                if rid < 0:
-                    continue
-                req = self.requests[rid]
-                tokens[s, l] = req.prompt[req.fed] if req.ramping \
-                    else req.output[-1]
-
-        # One variant launch per width class over its slot block.  An idle
-        # class skips its launch entirely (multi-class only: the
-        # single-class scheduler steps unconditionally, like it always
-        # has), and a skipped class's positions do not advance.
-        logits_by_class: list = [None] * len(self.classes)
-        released = set()
-        for c in self.classes:
-            sl = slice(c.start, c.start + c.n_slots)
-            cmask = mask[sl, :c.width]
-            if self.multiclass and not cmask.any():
-                continue
-            block_table = None
-            if self.paged:
-                # Map every live slot's write position to a page; empty
-                # slots write to the allocator's trash page.
-                c.allocator.ensure(self.pos[sl], cmask.sum(axis=1) > 0)
-                block_table = c.allocator.block_table
-            state = ServeState(cache=c.allocator.cache,
-                               pos=self.pos[sl].copy(),
-                               index_embeds=c.index_embeds,
-                               cross_kv=c.cross_kv)
-            toks = tokens[sl, :c.width] if c.mux_active \
-                else tokens[sl, 0]
-            logits, state = c.engine.step(state, toks, lane_mask=cmask,
-                                          block_table=block_table)
-            c.allocator.adopt(state.cache)
-            self.pos[sl] += 1
-            logits = np.asarray(logits)
-            if not c.mux_active:
-                logits = logits[:, None, :]              # (b, 1, V)
-            logits_by_class[c.index] = logits
-
-        for c in self.classes:
-            logits = logits_by_class[c.index]
-            if logits is None:
-                continue
-            for s in c.slots:
-                for l in range(c.width):
+        with self.tracer.span("sched.feed"):
+            mask = self.table.lane_mask()                # (B, N_max)
+            tokens = np.zeros((self.n_slots, self.n_lanes), np.int32)
+            for s in range(self.n_slots):
+                for l in range(self.n_lanes):
                     rid = int(self.table.grid[s, l])
                     if rid < 0:
                         continue
                     req = self.requests[rid]
-                    if req.ramping:
-                        req.fed += 1
-                        if req.ramping:  # prompt not fully consumed yet
+                    tokens[s, l] = req.prompt[req.fed] if req.ramping \
+                        else req.output[-1]
+
+            # One variant launch per width class over its slot block.  An
+            # idle class skips its launch entirely (multi-class only: the
+            # single-class scheduler steps unconditionally, like it always
+            # has), and a skipped class's positions do not advance.
+            launched = []
+            for c in self.classes:
+                sl = slice(c.start, c.start + c.n_slots)
+                cmask = mask[sl, :c.width]
+                if self.multiclass and not cmask.any():
+                    continue
+                block_table = None
+                if self.paged:
+                    # Map every live slot's write position to a page; empty
+                    # slots write to the allocator's trash page.
+                    c.allocator.ensure(self.pos[sl], cmask.sum(axis=1) > 0)
+                    block_table = c.allocator.block_table
+                state = ServeState(cache=c.allocator.cache,
+                                   pos=self.pos[sl].copy(),
+                                   index_embeds=c.index_embeds,
+                                   cross_kv=c.cross_kv)
+                toks = tokens[sl, :c.width] if c.mux_active \
+                    else tokens[sl, 0]
+                logits, state = c.engine.step(state, toks, lane_mask=cmask,
+                                              block_table=block_table)
+                c.allocator.adopt(state.cache)
+                self.pos[sl] += 1
+                launched.append((c, logits))
+
+        logits_by_class = self._read_back(launched)       # (b, w, V)
+        released = set()
+        with self.tracer.span("sched.sample"):
+            for c in self.classes:
+                logits = logits_by_class[c.index]
+                if logits is None:
+                    continue
+                for s in c.slots:
+                    for l in range(c.width):
+                        rid = int(self.table.grid[s, l])
+                        if rid < 0:
                             continue
-                    self._emit(req, logits[c.local(s), l], s, l, released)
+                        req = self.requests[rid]
+                        if req.ramping:
+                            req.fed += 1
+                            if req.ramping:  # prompt not fully consumed yet
+                                continue
+                        self._emit(req, logits[c.local(s), l], s, l,
+                                   released)
         return mask, released, None
 
     def _run_chunked_step(self):
@@ -1108,79 +1126,79 @@ class ContinuousScheduler:
         token — their extra chunk rows masked out of the mixed stream and
         the logits (``lane_mask`` is (B, N, C) here)."""
         C = self.chunk
-        mask = self.table.lane_mask()                    # (B, N_max) occup.
-        tokens = np.zeros((self.n_slots, self.n_lanes, C), np.int32)
-        contrib = np.zeros((self.n_slots, self.n_lanes, C), np.float32)
-        valid = np.ones(self.n_slots, np.int32)          # rows per slot
-        takes = np.zeros((self.n_slots, self.n_lanes), np.int32)
-        for s in range(self.n_slots):
-            for l in range(self.n_lanes):
-                rid = int(self.table.grid[s, l])
-                if rid < 0:
-                    continue
-                req = self.requests[rid]
-                if req.ramping:
-                    take = min(C, len(req.prompt) - req.fed)
-                    tokens[s, l, :take] = req.prompt[req.fed:req.fed + take]
-                    contrib[s, l, :take] = 1.0
-                    takes[s, l] = take
-                    valid[s] = max(valid[s], take)
-                else:
-                    tokens[s, l, 0] = req.output[-1]
-                    contrib[s, l, 0] = 1.0
-
-        logits_by_class: list = [None] * len(self.classes)
-        released = set()
-        for c in self.classes:
-            sl = slice(c.start, c.start + c.n_slots)
-            cmask = mask[sl, :c.width]
-            if self.multiclass and not cmask.any():
-                valid[sl] = 0            # skipped class: no position take
-                continue
-            block_table = None
-            if self.paged:
-                # Map every live slot's write range [pos, pos+valid) to
-                # pages.
-                c.allocator.ensure(self.pos[sl], cmask.sum(axis=1) > 0,
-                                   lens=valid[sl])
-                block_table = c.allocator.block_table
-            state = ServeState(cache=c.allocator.cache,
-                               pos=self.pos[sl].copy(),
-                               index_embeds=c.index_embeds,
-                               cross_kv=c.cross_kv)
-            ctoks = tokens[sl, :c.width, :] if c.mux_active \
-                else tokens[sl, 0, :]
-            logits, state = c.engine.step(state, ctoks,
-                                          lane_mask=contrib[sl, :c.width],
-                                          block_table=block_table,
-                                          chunk_lens=valid[sl])
-            c.allocator.adopt(state.cache)
-            self.pos[sl] += valid[sl]
-            logits = np.asarray(logits)                  # (b, w, C, V)
-            if not c.mux_active:
-                logits = logits[:, None, :, :]           # (b, 1, C, V)
-            logits_by_class[c.index] = logits
-
-        for c in self.classes:
-            logits = logits_by_class[c.index]
-            if logits is None:
-                continue
-            for s in c.slots:
-                for l in range(c.width):
+        with self.tracer.span("sched.feed"):
+            mask = self.table.lane_mask()                # (B, N_max) occup.
+            tokens = np.zeros((self.n_slots, self.n_lanes, C), np.int32)
+            contrib = np.zeros((self.n_slots, self.n_lanes, C), np.float32)
+            valid = np.ones(self.n_slots, np.int32)      # rows per slot
+            takes = np.zeros((self.n_slots, self.n_lanes), np.int32)
+            for s in range(self.n_slots):
+                for l in range(self.n_lanes):
                     rid = int(self.table.grid[s, l])
                     if rid < 0:
                         continue
                     req = self.requests[rid]
                     if req.ramping:
-                        take = int(takes[s, l])
-                        req.fed += take
-                        if req.ramping:  # prompt not fully consumed yet
-                            continue
-                        row = take - 1   # first token: last prompt row
+                        take = min(C, len(req.prompt) - req.fed)
+                        tokens[s, l, :take] = \
+                            req.prompt[req.fed:req.fed + take]
+                        contrib[s, l, :take] = 1.0
+                        takes[s, l] = take
+                        valid[s] = max(valid[s], take)
                     else:
-                        row = 0
-                    self._emit(req, logits[c.local(s), l, row], s, l,
-                               released)
+                        tokens[s, l, 0] = req.output[-1]
+                        contrib[s, l, 0] = 1.0
+
+            launched = []
+            for c in self.classes:
+                sl = slice(c.start, c.start + c.n_slots)
+                cmask = mask[sl, :c.width]
+                if self.multiclass and not cmask.any():
+                    valid[sl] = 0        # skipped class: no position take
+                    continue
+                block_table = None
+                if self.paged:
+                    # Map every live slot's write range [pos, pos+valid) to
+                    # pages.
+                    c.allocator.ensure(self.pos[sl], cmask.sum(axis=1) > 0,
+                                       lens=valid[sl])
+                    block_table = c.allocator.block_table
+                state = ServeState(cache=c.allocator.cache,
+                                   pos=self.pos[sl].copy(),
+                                   index_embeds=c.index_embeds,
+                                   cross_kv=c.cross_kv)
+                ctoks = tokens[sl, :c.width, :] if c.mux_active \
+                    else tokens[sl, 0, :]
+                logits, state = c.engine.step(
+                    state, ctoks, lane_mask=contrib[sl, :c.width],
+                    block_table=block_table, chunk_lens=valid[sl])
+                c.allocator.adopt(state.cache)
+                self.pos[sl] += valid[sl]
+                launched.append((c, logits))
+
+        logits_by_class = self._read_back(launched)       # (b, w, C, V)
+        released = set()
+        with self.tracer.span("sched.sample"):
+            for c in self.classes:
+                logits = logits_by_class[c.index]
+                if logits is None:
+                    continue
+                for s in c.slots:
+                    for l in range(c.width):
+                        rid = int(self.table.grid[s, l])
+                        if rid < 0:
+                            continue
+                        req = self.requests[rid]
+                        if req.ramping:
+                            take = int(takes[s, l])
+                            req.fed += take
+                            if req.ramping:  # prompt not fully consumed yet
+                                continue
+                            row = take - 1   # first token: last prompt row
+                        else:
+                            row = 0
+                        self._emit(req, logits[c.local(s), l, row], s, l,
+                                   released)
         return mask, released, valid
 
     def _emit(self, req: Request, lane_logits, s: int, l: int,
@@ -1273,22 +1291,6 @@ class ContinuousScheduler:
                 m.gauge("peak_pages",
                         sum(c.allocator.table.peak_in_use
                             for c in self.classes))
-                if self.engine.cfg.serving.use_kernel:
-                    # PR 7's bench-only grid probe, lifted into telemetry:
-                    # grid steps and compute-skipped K-blocks of this
-                    # step's kernel launch (per layer — every layer runs
-                    # the same grid over the same block table; width
-                    # classes launch one grid per class, summed here).
-                    grid = skipped = 0
-                    for c in self.classes:
-                        g, sk, _ = kblock_stats(
-                            np.asarray(c.allocator.table.rows),
-                            c.engine.cfg.serving.kblock_pages,
-                            c.engine.cfg.n_kv_heads)
-                        grid += g
-                        skipped += sk
-                    m.count("kernel_grid_steps", grid)
-                    m.count("kernel_skipped_blocks", skipped)
             tr.snap(self.t)
         self.t += 1
 

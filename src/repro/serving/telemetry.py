@@ -1,29 +1,36 @@
-"""Serving telemetry: request-lifecycle tracing, metrics, Perfetto export.
+"""Serving telemetry: request-lifecycle tracing, metrics, host spans,
+Perfetto export.
 
 The serving stack spans continuous batching, paged KV, preempt-and-swap,
-and a replica router, but until now its only view was ``--report`` print
-lines — when a preemption storm or router backpressure stall happens,
-nothing records *when* or *why*.  This module is the observability layer
-the ROADMAP's heavy-traffic items need:
+and a replica router.  This module records *when* and *why* things
+happen in it:
 
   * ``Tracer`` — an in-memory event recorder threaded through
-    ``ContinuousScheduler``, ``ReplicaRouter``, ``PagedKVSlotAllocator``,
-    ``SwapLedger``, and ``Engine.step``.  Per-request lifecycle events
-    (submit → dispatch/requeue → admit → first_token → preempt/resume →
-    retire, or reject) and per-step timeline events (slot decode/ramp,
-    page alloc/free, swap in/out, idle gaps) are recorded as typed
-    ``TraceEvent`` rows with the scheduler step as the clock.
+    ``ContinuousScheduler``, ``ReplicaRouter``, ``PagedKVSlotAllocator``
+    and ``SwapLedger``.  Per-request lifecycle events (submit →
+    dispatch/requeue → admit → first_token → preempt/resume → retire, or
+    reject) and per-step timeline events (slot decode/ramp, page
+    alloc/free, swap in/out, idle gaps) are recorded as typed
+    ``TraceEvent`` rows, each with the scheduler step (``ts``) and the
+    host wall clock (``wall_ns``) it happened at.
+  * Host spans — ``span(name, **stats)`` on every recorder handle enters
+    a ``jax.profiler.TraceAnnotation``, so the span lands on the
+    profiler's host plane beside the device's operations whenever a
+    profile is running (``ContinuousScheduler.step`` opens ``sched.admit``,
+    ``sched.feed``, ``sched.readback`` with its ``bytes`` stat,
+    ``sched.sample`` and ``sched.release``).  An enabled scope also keeps
+    the span as a ``TraceEvent`` with its wall-clock duration.
   * ``MetricsRegistry`` — named monotonic counters and point-in-time
-    gauges (tokens, free pages, queue depth, preemptions, kernel
-    grid-steps/skipped-blocks) with one ``snap()`` row per step, exported
-    as JSONL (one JSON object per line: ``{"step": t, "r0/free_pages":
-    ..., ...}``; metric names are prefixed ``r{replica}/`` or
-    ``router/`` by the scope that recorded them).
+    gauges (tokens, free pages, queue depth, preemptions) with one
+    ``snap()`` row per step, exported as JSONL (one JSON object per line:
+    ``{"step": t, "r0/free_pages": ..., ...}``; metric names are prefixed
+    ``r{replica}/`` or ``router/`` by the scope that recorded them).
   * Chrome/Perfetto export — ``Tracer.chrome_trace()`` renders the event
-    log as a ``traceEvents`` JSON (load it at https://ui.perfetto.dev):
-    one process per replica (plus one for the router), one thread per
-    slot with ``X`` duration events per decode step, async span trees per
-    request (``queued`` → ``ramp``/``decode`` with ``parked``
+    log as a ``traceEvents`` JSON (load it at https://ui.perfetto.dev) on
+    the wall clock: one process per replica (plus one for the router),
+    the ``sched.*`` spans as ``X`` events on its scheduler thread, one
+    thread per slot with an ``X`` event per decode step, async span trees
+    per request (``queued`` → ``ramp``/``decode`` with ``parked``
     interruptions), instant events for page/swap traffic, and ``C``
     counter tracks from the metric rows.
 
@@ -31,27 +38,26 @@ Zero-overhead contract: every recorder handle defaults to the
 ``NULL_TRACER`` singleton whose methods are no-ops and whose ``enabled``
 flag gates all non-trivial collection, so a serve without ``--trace`` /
 ``--metrics`` executes the exact pre-telemetry path — bitwise-identical
-tokens, step counts, and page traffic.  Telemetry never feeds back into
-scheduling: a traced run is bitwise-identical to an untraced one too
-(pinned in ``tests/test_telemetry.py``).
+tokens, step counts, and page traffic.  Its ``span`` is the profiler's
+annotation alone, which costs about a microsecond when no profile runs.
+Telemetry never feeds back into scheduling: a traced run is
+bitwise-identical to an untraced one too (pinned in
+``tests/test_telemetry.py``).
 
-The scheduler-side clock is the *decode step*, not wall time — spans are
-exact replays of scheduler decisions, so tests can assert span sequence ==
-scheduler event log.  Export maps one step to ``STEP_US`` microseconds so
-Perfetto renders readable track widths; ``Engine.step`` additionally
-stamps host wall-clock dispatch time per step as an instant event.
+``ts``, the scheduler step, is the clock the lifecycle checks replay
+(span sequence == scheduler event log); ``wall_ns`` (``time.time_ns()``,
+the clock of the profiler's host plane) is the clock of the export.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
-from typing import Any, Iterable, Optional
+import time
+from typing import Optional
 
 import numpy as np
-
-# Chrome trace timestamps are microseconds; one scheduler step renders as
-# 1ms so smoke-scale traces are legible without zooming.
-STEP_US = 1000
+from jax.profiler import TraceAnnotation
 
 # Scope id the router records under (replicas use their index >= 0).
 ROUTER_SCOPE = -1
@@ -66,7 +72,10 @@ class TraceEvent:
     """One recorded event.  ``ts`` is the scheduler clock in steps;
     ``seq`` is a global tiebreaker preserving emission order within a
     step.  ``rid`` is set for lifecycle events, ``slot`` for slot-scoped
-    timeline events; ``args`` carries kind-specific detail."""
+    timeline events; ``args`` carries kind-specific detail (a span's
+    stats).  ``wall_ns`` is ``time.time_ns()`` when the event was recorded
+    or, for a span, when it opened; ``dur_ns`` is a span's wall duration
+    and None for every other event."""
     ts: int
     seq: int
     kind: str
@@ -75,6 +84,8 @@ class TraceEvent:
     slot: Optional[int] = None
     lane: Optional[int] = None
     args: dict = dataclasses.field(default_factory=dict)
+    wall_ns: int = 0
+    dur_ns: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -91,6 +102,7 @@ class MetricsRegistry:
         self.counters: dict[str, float] = {}
         self.gauges: dict[str, float] = {}
         self.rows: list[dict] = []
+        self.row_wall_ns: list[int] = []     # wall clock of each row
 
     def count(self, name: str, value: float = 1) -> None:
         self.counters[name] = self.counters.get(name, 0) + value
@@ -106,6 +118,7 @@ class MetricsRegistry:
         """Append (and return) one per-step snapshot row."""
         row = {"step": int(step), **self.snapshot()}
         self.rows.append(row)
+        self.row_wall_ns.append(time.time_ns())
         return row
 
     def write_jsonl(self, path: str) -> int:
@@ -162,6 +175,11 @@ class NullTracer:
     def event(self, kind: str, **kw) -> None:
         pass
 
+    def span(self, name: str, **stats) -> TraceAnnotation:
+        """The profiler's host annotation alone: it lands in a running
+        profile, and nothing is kept here."""
+        return TraceAnnotation(name, **stats)
+
     def snap(self, step: int) -> None:
         pass
 
@@ -210,7 +228,20 @@ class _Scope:
         self.tracer.record(TraceEvent(
             ts=int(self.now if ts is None else ts), seq=self.tracer.next_seq(),
             kind=kind, replica=self.replica, rid=rid, slot=slot, lane=lane,
-            args=args))
+            args=args, wall_ns=time.time_ns()))
+
+    @contextlib.contextmanager
+    def span(self, name: str, **stats):
+        """A host span: the profiler's annotation, kept here too as a
+        ``TraceEvent`` of kind ``name`` with its wall-clock start and
+        duration and ``stats`` as its args."""
+        t0 = time.time_ns()
+        with TraceAnnotation(name, **stats):
+            yield
+        self.tracer.record(TraceEvent(
+            ts=int(self.now), seq=self.tracer.next_seq(), kind=name,
+            replica=self.replica, args=stats, wall_ns=t0,
+            dur_ns=time.time_ns() - t0))
 
     def snap(self, step: int) -> None:
         if self.owns_snapshots:
@@ -334,16 +365,23 @@ class Tracer:
 
     def chrome_trace(self) -> dict:
         """Render the event log as Chrome ``traceEvents`` JSON (Perfetto
-        loads it directly): per-replica processes, per-slot threads with
-        duration events for each decode/ramp step, async span trees per
-        request, instants for page/swap traffic, counter tracks from the
-        metric rows."""
+        loads it directly) on the wall clock, in microseconds since the
+        first event: per-replica processes, the ``sched.*`` spans as
+        duration events on the scheduler thread, per-slot threads with a
+        duration event for each decode/ramp step (the step's span
+        bounds), async span trees per request, instants for page/swap
+        traffic, counter tracks from the metric rows."""
         out: list[dict] = []
         replicas = sorted({e.replica for e in self.events
                            if e.replica != ROUTER_SCOPE}) or [0]
         max_rep = max(replicas)
         pids = {r: self._pid(r, max_rep)
                 for r in set([e.replica for e in self.events] + [0])}
+        origin = min([e.wall_ns for e in self.events]
+                     + self.metrics.row_wall_ns, default=0)
+
+        def us(wall_ns: int) -> float:
+            return (wall_ns - origin) / 1e3
 
         # Process/thread naming metadata.
         for r, pid in sorted(pids.items()):
@@ -363,27 +401,39 @@ class Tracer:
                if (k := json.dumps(r, sort_keys=True)) not in seen
                and not seen.add(k)]
 
+        # Wall-clock bounds of each (replica, step) from its host spans.
+        bounds: dict[tuple[int, int], list[int]] = {}
+        for e in self.events:
+            if e.dur_ns is not None:
+                lo_hi = bounds.setdefault((e.replica, e.ts),
+                                          [e.wall_ns, e.wall_ns + e.dur_ns])
+                lo_hi[0] = min(lo_hi[0], e.wall_ns)
+                lo_hi[1] = max(lo_hi[1], e.wall_ns + e.dur_ns)
+
         # Timeline events.
         for e in self.events:
             pid = pids[e.replica]
-            us = e.ts * STEP_US
-            if e.kind == "slot_step":
-                adv = int(e.args.get("advance", 1))
+            if e.dur_ns is not None:
+                out.append({"ph": "X", "name": e.kind, "cat": "host",
+                            "pid": pid, "tid": 0, "ts": us(e.wall_ns),
+                            "dur": e.dur_ns / 1e3, "args": e.args})
+            elif e.kind == "slot_step":
+                lo, hi = bounds.get((e.replica, e.ts),
+                                    (e.wall_ns, e.wall_ns))
                 out.append({
                     "ph": "X", "name": "ramp" if e.args.get("ramping")
                     else "decode", "cat": "step", "pid": pid,
-                    "tid": e.slot + 1, "ts": us, "dur": adv * STEP_US,
+                    "tid": e.slot + 1, "ts": us(lo), "dur": (hi - lo) / 1e3,
                     "args": e.args})
             elif e.kind in ("page_alloc", "page_free", "swap_out", "swap_in",
-                            "engine_step", "idle", "dispatch", "requeue",
-                            "reject"):
+                            "idle", "dispatch", "requeue", "reject"):
                 tid = 0 if e.slot is None else e.slot + 1
                 args = dict(e.args)
                 if e.rid is not None:
                     args["rid"] = e.rid
                 out.append({"ph": "i", "s": "t", "name": e.kind,
                             "cat": "timeline", "pid": pid, "tid": tid,
-                            "ts": us, "args": args})
+                            "ts": us(e.wall_ns), "args": args})
 
         # Async span tree per request, replayed from the lifecycle log.
         for rid in self.request_ids():
@@ -395,54 +445,52 @@ class Tracer:
             pid = pids.get(serve, pids[0])
             aid = str(rid)
 
-            def async_ev(ph, name, ts):
+            def async_ev(ph, name, ev):
                 return {"ph": ph, "name": name, "cat": "request", "id": aid,
-                        "pid": pid, "tid": 0, "ts": ts * STEP_US}
+                        "pid": pid, "tid": 0, "ts": us(ev.wall_ns)}
 
-            open_seg = None                   # (name, since-ts)
+            open_seg = None                   # segment name
             interrupted = None                # segment name a park paused
-            last_ts = log[-1].ts
             emitted: list[dict] = []
             for e in log:
                 if e.kind == "submit":
-                    emitted.append(async_ev("b", f"request {rid}", e.ts))
-                    open_seg = ("queued", e.ts)
-                    emitted.append(async_ev("b", "queued", e.ts))
+                    emitted.append(async_ev("b", f"request {rid}", e))
+                    open_seg = "queued"
+                    emitted.append(async_ev("b", "queued", e))
                 elif e.kind == "admit":
                     if open_seg:
-                        emitted.append(async_ev("e", open_seg[0], e.ts))
-                    open_seg = ("ramp", e.ts)
-                    emitted.append(async_ev("b", "ramp", e.ts))
+                        emitted.append(async_ev("e", open_seg, e))
+                    open_seg = "ramp"
+                    emitted.append(async_ev("b", "ramp", e))
                 elif e.kind == "first_token":
-                    emitted.append(async_ev("n", "first_token", e.ts))
-                    if open_seg and open_seg[0] == "ramp":
-                        emitted.append(async_ev("e", "ramp", e.ts))
-                        open_seg = ("decode", e.ts)
-                        emitted.append(async_ev("b", "decode", e.ts))
+                    emitted.append(async_ev("n", "first_token", e))
+                    if open_seg == "ramp":
+                        emitted.append(async_ev("e", "ramp", e))
+                        open_seg = "decode"
+                        emitted.append(async_ev("b", "decode", e))
                 elif e.kind == "preempt":
                     if open_seg:
-                        emitted.append(async_ev("e", open_seg[0], e.ts))
-                        interrupted = open_seg[0]
-                    open_seg = ("parked", e.ts)
-                    emitted.append(async_ev("b", "parked", e.ts))
+                        emitted.append(async_ev("e", open_seg, e))
+                        interrupted = open_seg
+                    open_seg = "parked"
+                    emitted.append(async_ev("b", "parked", e))
                 elif e.kind == "resume":
                     if open_seg:
-                        emitted.append(async_ev("e", open_seg[0], e.ts))
-                    open_seg = (interrupted or "decode", e.ts)
-                    emitted.append(async_ev("b", open_seg[0], e.ts))
+                        emitted.append(async_ev("e", open_seg, e))
+                    open_seg = interrupted or "decode"
+                    emitted.append(async_ev("b", open_seg, e))
                 elif e.kind == "retire":
                     if open_seg:
-                        emitted.append(async_ev("e", open_seg[0], e.ts))
+                        emitted.append(async_ev("e", open_seg, e))
                         open_seg = None
-                    emitted.append(async_ev("e", f"request {rid}", e.ts))
+                    emitted.append(async_ev("e", f"request {rid}", e))
             if open_seg:                      # max_steps bail: close cleanly
-                emitted.append(async_ev("e", open_seg[0], last_ts))
-                emitted.append(async_ev("e", f"request {rid}", last_ts))
+                emitted.append(async_ev("e", open_seg, log[-1]))
+                emitted.append(async_ev("e", f"request {rid}", log[-1]))
             out.extend(emitted)
 
         # Counter tracks from the per-step metric rows.
-        for row in self.metrics.rows:
-            us = row["step"] * STEP_US
+        for row, wall_ns in zip(self.metrics.rows, self.metrics.row_wall_ns):
             for key, value in row.items():
                 if key == "step":
                     continue
@@ -451,11 +499,12 @@ class Tracer:
                     else pids.get(int(scope[1:]) if scope[1:].isdigit()
                                   else 0, pids[0])
                 out.append({"ph": "C", "name": name, "cat": "metrics",
-                            "pid": pid, "tid": 0, "ts": us,
+                            "pid": pid, "tid": 0, "ts": us(wall_ns),
                             "args": {"value": value}})
 
         return {"traceEvents": out, "displayTimeUnit": "ms",
-                "metadata": {"clock": f"scheduler step ({STEP_US} us/step)",
+                "metadata": {"clock": "wall (us since wall_origin_ns)",
+                             "wall_origin_ns": origin,
                              "steps": max((e.ts for e in self.events),
                                           default=0)}}
 
@@ -468,7 +517,7 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# Kernel grid accounting (lifted from the PR 7 bench-only probe)
+# Kernel grid accounting (``benchmarks/decode_kernel.py``)
 # ---------------------------------------------------------------------------
 
 def kblock_stats(block_table: np.ndarray, kblock: int,

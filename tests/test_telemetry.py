@@ -203,7 +203,6 @@ def test_metrics_rows_and_summary():
 def test_null_tracer_is_inert_default():
     sched = _build()
     assert not sched.tracer.enabled
-    assert sched.engine.tracer is sched.tracer
     assert sched.allocator.tracer is sched.tracer
     assert as_scope(None) is NULL_TRACER
     assert isinstance(NULL_TRACER, NullTracer)
@@ -227,3 +226,145 @@ def test_first_token_step_is_deprecated_alias():
                          max_new_tokens=2)
     with pytest.warns(DeprecationWarning):
         assert unfinished.first_token_step == -1
+
+
+# ---------------------------------------------------------------------------
+# Host spans and device scopes: what a profile of the serving path shows
+# ---------------------------------------------------------------------------
+
+SCHED_SPANS = ("sched.admit", "sched.feed", "sched.readback", "sched.sample",
+               "sched.release")
+DEVICE_SCOPES = ("mux", "attention", "kv_write", "mlp", "demux", "lm_head")
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under the profiler (host spans only); return the events
+    of the python thread as (name, wall start ns, duration ns, stats) and
+    ``fn``'s result."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    origin = next(dict(p.stats)["profile_start_time"] for p in data.planes
+                  if p.name == "Task Environment")
+    events = [(e.name, origin + e.start_ns, e.duration_ns, dict(e.stats))
+              for p in data.planes if p.name == "/host:CPU"
+              for line in p.lines if line.name.startswith("python")
+              for e in line.events]
+    return events, out
+
+
+def test_scheduler_step_spans_in_a_profile(tmp_path):
+    """Profiled on the CPU, each step of the tiny paged scheduler shows
+    the five ``sched.*`` spans once, inside the caller's span and in
+    order, and ``sched.readback``'s ``bytes`` is the logits' size."""
+    sched = _build()
+    rng = np.random.default_rng(3)
+    for i in range(6):
+        sched.submit(Request(
+            rid=i, prompt=rng.integers(0, CFG.vocab, 3).astype(np.int32),
+            max_new_tokens=4))
+    n_steps = 5
+
+    def steps():
+        for _ in range(n_steps):
+            with jax.profiler.TraceAnnotation("step"):
+                sched.step()
+
+    events, _ = _profile(tmp_path, steps)
+    outer = sorted((a, a + d) for n, a, d, _ in events if n == "step")
+    assert len(outer) == n_steps
+    logits_bytes = N_SLOTS * CFG.mux.n * CFG.vocab * 4      # f32 logits
+    for lo, hi in outer:
+        inside = sorted((a, n, d, st) for n, a, d, st in events
+                        if n in SCHED_SPANS and lo <= a and a + d <= hi)
+        assert [n for _, n, _, _ in inside] == list(SCHED_SPANS)
+        ends = [a + d for a, _, d, _ in inside]
+        starts = [a for a, _, _, _ in inside]
+        assert all(e <= s for e, s in zip(ends, starts[1:]))
+        assert inside[2][3] == {"bytes": logits_bytes}
+    assert not [n for n, a, _, _ in events if n in SCHED_SPANS
+                and not any(lo <= a <= hi for lo, hi in outer)]
+
+
+def test_tracer_span_agrees_with_the_profiler(tmp_path):
+    """A ``Tracer`` span and the profiler's annotation it enters time the
+    same sleep on the same clock, within a millisecond."""
+    import time
+    scope = Tracer().scope(0)
+
+    def sleep():
+        with scope.span("probe", bytes=7):
+            time.sleep(0.02)
+
+    events, _ = _profile(tmp_path, sleep)
+    (_, start, dur, stats), = [e for e in events if e[0] == "probe"]
+    (ev,) = [e for e in scope.tracer.events if e.kind == "probe"]
+    assert stats == {"bytes": 7} and ev.args == {"bytes": 7}
+    assert abs(ev.wall_ns - start) < 1e6
+    assert abs(ev.dur_ns - dur) < 1e6
+    assert ev.dur_ns >= 0.02e9
+
+
+def test_null_tracer_span_keeps_nothing():
+    before = dict(vars(NULL_TRACER))
+    with NULL_TRACER.span("sched.readback", bytes=1):
+        pass
+    assert vars(NULL_TRACER) == before
+    assert isinstance(NULL_TRACER.span("x"), jax.profiler.TraceAnnotation)
+
+
+def test_traced_steps_export_spans_on_the_wall_clock(tmp_path):
+    """With a ``Tracer`` attached, every step keeps its five spans as
+    events, and the Chrome export puts them, in order, on the scheduler
+    thread, with each slot's step inside its step's span bounds."""
+    check = _check_trace_module()
+    tracer = Tracer()
+    sched = _build(tracer)
+    stats = sched.run([r.fresh() for r in _preempt_trace()[:4]])
+    spans = [e for e in tracer.events if e.dur_ns is not None]
+    assert [e.kind for e in spans] == list(SCHED_SPANS) * stats.decode_steps
+    path = str(tmp_path / "t.trace.json")
+    tracer.export_chrome(path)
+    assert check.check_trace(path) == []
+    doc = json.load(open(path))["traceEvents"]
+    host = [e for e in doc if e.get("cat") == "host"]
+    assert [e["name"] for e in host] == [e.kind for e in spans]
+    assert all(e["tid"] == 0 and e["ph"] == "X" for e in host)
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3
+               for a, b in zip(host, host[1:]))
+    slots = [e for e in doc if e.get("cat") == "step"]
+    assert slots
+    first, last = host[0]["ts"], host[-1]["ts"] + host[-1]["dur"]
+    assert all(first <= e["ts"] and e["ts"] + e["dur"] <= last + 1e-3
+               for e in slots)
+
+
+def test_decode_step_hlo_names_every_scope():
+    """The tiny engine's compiled decode step carries each named scope in
+    the metadata of at least one instruction."""
+    import re
+    sched = _build()
+    sched.submit(Request(rid=0, prompt=np.arange(3, dtype=np.int32),
+                         max_new_tokens=2))
+    held = {}
+    inner = sched.engine._step
+
+    def step(*args):
+        held["args"] = args
+        return inner(*args)
+
+    sched.engine._step = step
+    sched.step()
+    abstract = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                            held["args"])
+    text = inner.lower(*abstract).compile().as_text()
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', text)]
+    for scope in DEVICE_SCOPES:
+        assert any(scope in p for p in paths), scope
