@@ -135,11 +135,12 @@ def _paged_kernel(bt_ref, qp_ref, q_ref, *refs, scale: float, causal: bool,
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "causal", "window",
-                                    "kblock_pages", "interpret"))
+                                    "kblock_pages", "kv_heads", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, pos_pages, block_table,
                            q_pos, *, scale: float, causal: bool = True,
                            window: Optional[int] = None,
                            kblock_pages: int = 1,
+                           kv_heads: Optional[int] = None,
                            interpret: bool = False):
     """q: (B, C, H, hd); k_pages/v_pages: (P, ps, KVH, hd); pos_pages:
     (P, ps) int32; block_table: (B, max_pages) int32; q_pos: (B, C) int32.
@@ -148,10 +149,13 @@ def paged_decode_attention(q, k_pages, v_pages, pos_pages, block_table,
     ``kblock_pages``: block-table entries spanned per kernel invocation —
     the grid's K axis shrinks to ceil(max_pages / kblock_pages) and each
     step runs one (kblock_pages·ps)-row dot_general.  1 = the historical
-    page-at-a-time grid, bit-identical.
+    page-at-a-time grid, bit-identical.  ``kv_heads``: the real heads of
+    pools padded to ``nn.attention.pool_kv_heads`` (default all); the grid
+    visits those alone.
     """
     b, c, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
+    _, ps, pool_heads, _ = k_pages.shape
+    kvh = kv_heads or pool_heads
     n_rep = h // kvh
     kblock = int(kblock_pages)
     tiling.validate_kblock(kblock, ps, hd, itemsize=k_pages.dtype.itemsize)
@@ -172,8 +176,8 @@ def paged_decode_attention(q, k_pages, v_pages, pos_pages, block_table,
     rows = c * n_rep
     qr = q.reshape(b, c, kvh, n_rep, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(b, kvh, rows, hd)
-    k_view = k_pages.reshape(k_pages.shape[0], ps, kvh * hd)
-    v_view = v_pages.reshape(v_pages.shape[0], ps, kvh * hd)
+    k_view = k_pages.reshape(k_pages.shape[0], ps, pool_heads * hd)
+    v_view = v_pages.reshape(v_pages.shape[0], ps, pool_heads * hd)
     pos_view = pos_pages.reshape(pos_pages.shape[0], 1, ps)
 
     def page_spec(j):

@@ -15,7 +15,13 @@ ModelConfig:
     registry (``repro.core.strategies``), so new codecs plug in without
     touching this file.  ``cfg.mux.n == 1`` degrades to a vanilla LM.
   * Decode mode threads per-layer caches (KV / ring-buffer / MLA-latent /
-    SSM state) through the same scan.
+    SSM state) through the same scan: each layer's cache is sliced out of
+    the stacked caches (``xs``) and written back into fresh ones (``ys``).
+    Paged GQA pools are the exception: they ride the scan's carry whole,
+    indexed by the layer number, so XLA updates them in place; their pages
+    hold whole tiles of heads (``nn.attention.pool_kv_heads``), so the
+    device's default layout is the one of the in-loop writes and no slice
+    or copy of a pool is left.
 """
 from __future__ import annotations
 
@@ -103,10 +109,25 @@ def _layer_cache(cfg: ModelConfig, kind: dict, batch: int, max_len: int,
     raise ValueError(mixer)
 
 
+def carries_pool(layer_cache) -> bool:
+    """Whether a scanned layer's decode cache rides the layer scan's carry:
+    a paged GQA pool (``Attention.init_paged_cache``), stacked over the
+    groups.  Every other cache — contiguous K/V, windowed rings, MLA
+    latents, SSM and xLSTM state — is sliced per layer (``xs``/``ys``)."""
+    return isinstance(layer_cache, dict) and "k_pages" in layer_cache
+
+
+def pool_layers_in_carry(cache) -> int:
+    """Scanned layers of ``cache`` (arrays or shapes) whose paged pools
+    ride the layer scan's carry."""
+    return sum(layer["k_pages"].shape[0] for layer in cache["blocks"]
+               if carries_pool(layer))
+
+
 def _layer_apply(p, x, cfg: ModelConfig, kind: dict, *, positions,
                  cache=None, cache_index=None, cross_kv=None,
                  block_table=None, chunk_lens=None, row_mask=None, mesh=None,
-                 mesh_info: MeshInfo = SINGLE):
+                 mesh_info: MeshInfo = SINGLE, layer=None):
     norm = make_norm(cfg.norm)
     mixer = kind["mixer"]
     aux = jnp.zeros((), jnp.float32)
@@ -126,7 +147,7 @@ def _layer_apply(p, x, cfg: ModelConfig, kind: dict, *, positions,
             out, new_cache = Attention.apply(
                 p["attn"], h, cfg.attn_config(window=kind["window"]),
                 positions=positions, cache=cache, cache_index=cache_index,
-                block_table=block_table, chunk_lens=chunk_lens)
+                block_table=block_table, chunk_lens=chunk_lens, layer=layer)
         elif mixer == "mla":
             out, new_cache = MLA.apply(p["attn"], h, cfg.mla,
                                        positions=positions, cache=cache,
@@ -335,13 +356,14 @@ class Backbone:
             sp_spec = jax.sharding.PartitionSpec(bat, seq,
                                                  mesh_info.model_axis)
 
-        def run_one(lp, x, kind, lcache, ckv):
+        def run_one(lp, x, kind, lcache, ckv, layer=None):
             x, nc, aux = _layer_apply(lp, x, cfg, kind, positions=positions,
                                       cache=lcache, cache_index=cache_index,
                                       cross_kv=ckv, block_table=block_table,
                                       chunk_lens=chunk_lens,
                                       row_mask=row_mask,
-                                      mesh=mesh, mesh_info=mesh_info)
+                                      mesh=mesh, mesh_info=mesh_info,
+                                      layer=layer)
             if sp_spec is not None:
                 x = _constrain(x, mesh, sp_spec)
             return x, nc, aux
@@ -357,17 +379,28 @@ class Backbone:
 
         # scanned groups
         if groups:
-            def group_body(x, sliced):
-                lps, lcs, ckvs = sliced
+            stacked_lcs = cache["blocks"] if cache is not None else None
+            # Paged GQA pools ride the carry whole (indexed by the group
+            # number ``g``); every other stacked cache is sliced per group.
+            pools = {j: lc for j, lc in enumerate(stacked_lcs or [])
+                     if carries_pool(lc)}
+
+            def group_body(carry, sliced):
+                x, pools = carry
+                lps, lcs, ckvs, g = sliced
+                pools = dict(pools)
                 aux_g = jnp.zeros((), jnp.float32)
                 ncs = []
                 for j in range(period):
-                    x, nc, aux = run_one(lps[j], x, kinds[head + j],
-                                         lcs[j] if lcs is not None else None,
-                                         ckvs.get(j) if ckvs else None)
+                    lc = pools.get(j, lcs[j] if lcs is not None else None)
+                    x, nc, aux = run_one(lps[j], x, kinds[head + j], lc,
+                                         ckvs.get(j) if ckvs else None,
+                                         layer=g if j in pools else None)
                     aux_g = aux_g + aux
+                    if j in pools:
+                        pools[j], nc = nc, None
                     ncs.append(nc)
-                return x, (ncs if lcs is not None else None, aux_g)
+                return (x, pools), (ncs if lcs is not None else None, aux_g)
 
             if cfg.remat == "full":
                 group_body = jax.checkpoint(group_body)
@@ -378,17 +411,21 @@ class Backbone:
                     .dots_with_no_batch_dims_saveable)
 
             stacked_lps = params["blocks"]  # list over pattern positions
-            stacked_lcs = cache["blocks"] if cache is not None else None
+            sliced_lcs = None if stacked_lcs is None else [
+                None if j in pools else lc
+                for j, lc in enumerate(stacked_lcs)]
             block_ckvs = (cross_kv or {}).get("blocks", {}) or None
-            x, (ncs, aux_g) = jax.lax.scan(
-                group_body, x,
+            (x, pools), (ncs, aux_g) = jax.lax.scan(
+                group_body, (x, pools),
                 (stacked_lps,
-                 stacked_lcs if stacked_lcs is not None else
+                 sliced_lcs if sliced_lcs is not None else
                  [None] * period if period else None,
-                 {j: v for j, v in (block_ckvs or {}).items()}))
+                 {j: v for j, v in (block_ckvs or {}).items()},
+                 jnp.arange(groups, dtype=jnp.int32) if pools else None))
             aux_total = aux_total + jnp.sum(aux_g)
             if new_cache is not None:
-                new_cache["blocks"] = ncs
+                new_cache["blocks"] = [pools.get(j, nc)
+                                       for j, nc in enumerate(ncs)]
 
         # tail (unscanned)
         tail_start = head + period * groups

@@ -56,6 +56,36 @@ def paged_eligible(window: Optional[int], max_len: int) -> bool:
     return window is None or window >= max_len
 
 
+def pool_kv_heads(n_kv_heads: int) -> int:
+    """K/V heads a paged pool page holds: ``n_kv_heads`` rounded up to a
+    whole number of 8-row tiles when it exceeds one tile.
+
+    A TPU tiles an array's two minor dimensions in 8-row tiles.  For a page
+    of ``(ps, KVH, hd)`` whose KVH fills no whole tile, XLA's default layout
+    puts ``ps`` second-minor instead (no padding), and the decode step's
+    in-place write of one ``(KVH, hd)`` row per slot into the stacked pool
+    then makes it relayout the whole pool into and out of the layer loop,
+    every step.  Padded heads give a row-major default layout, the one that
+    write wants, with no layout to pin.  (v5e compiler, bf16 and f32 pages
+    of 1-48 heads: row-major at 2, 4 and multiples of 8, ps second-minor at
+    every other count.)  Up to one tile the heads stay as they are: there
+    padding would cost up to 8x the pool.  The extra heads are written as
+    zeros and never read."""
+    if n_kv_heads <= 8:
+        return n_kv_heads
+    return -(-n_kv_heads // 8) * 8
+
+
+def _pool_rows(x, pool):
+    """K/V rows ``x`` (..., KVH, hd) in the pool's dtype, padded with zero
+    heads to the pool's head count (``pool_kv_heads``)."""
+    pad = pool.shape[-2] - x.shape[-2]
+    x = x.astype(pool.dtype)
+    if pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
+    return x
+
+
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
@@ -186,6 +216,14 @@ def masked_chunk_write(cache, idx, row_ok, values: dict, pos_q):
         return out
 
 
+def _pool_index(layer, page_ids, off):
+    """Index of the paged-pool rows a decode step writes: ``(page, offset)``
+    in one layer's ``(P, ps, ...)`` pool, with ``layer`` in front for the
+    layer scan's stacked ``(G, P, ps, ...)`` pool, which is then updated in
+    place (no per-layer slice is taken out of it)."""
+    return (page_ids, off) if layer is None else (layer, page_ids, off)
+
+
 def make_attention_mask(q_pos, k_pos, *, causal: bool, window: Optional[int],
                         k_valid=None):
     """Boolean (B, 1, Lq, Lk) mask from query/key positions.
@@ -244,8 +282,11 @@ class Attention:
         table (which lives in the ``PagedKVSlotAllocator``, not here — it is
         identical across layers).  ``pos`` mirrors the contiguous cache's
         written-position array per page; -1 = unwritten.  Page 0 is the
-        allocator's trash page (writes from empty slots land there)."""
-        shape = (pool_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        allocator's trash page (writes from empty slots land there).  A page
+        holds ``pool_kv_heads(n_kv_heads)`` heads, the first
+        ``n_kv_heads`` of them real."""
+        shape = (pool_pages, page_size, pool_kv_heads(cfg.n_kv_heads),
+                 cfg.head_dim)
         return {
             "k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype),
@@ -254,7 +295,8 @@ class Attention:
 
     @staticmethod
     def apply(params, x, cfg: AttnConfig, *, positions, cache=None,
-              cache_index=None, block_table=None, chunk_lens=None):
+              cache_index=None, block_table=None, chunk_lens=None,
+              layer=None):
         """x: (B, L, D). Returns (out, new_cache).
 
         Full-sequence mode (cache None): causal/window mask over x itself.
@@ -264,7 +306,10 @@ class Attention:
         its own position, so slots can be admitted/retired independently).
         Paged decode (cache holds ``k_pages``): ``block_table`` (B, max_pages)
         maps each slot's page index to a pool page; writes and the attention
-        gather go through the table.
+        gather go through the table.  With ``layer`` (a traced int32) the
+        pools are the scan's stacked ``(G, P, ps, ...)`` ones and this is
+        layer ``layer`` of them: writes and the gather index that layer in
+        place, and the whole stacked pool comes back.
         Chunked decode (``chunk_lens`` (B,) int32 given): L == C is a token
         chunk; row i of slot b sits at position ``positions[b, i]`` and only
         rows ``i < chunk_lens[b]`` are real — a ramping prompt writes C
@@ -284,7 +329,8 @@ class Attention:
 
         if cache is not None and chunk_lens is not None:
             out, new_cache = Attention._chunked_decode(
-                q, k, v, cfg, cache, positions, chunk_lens, block_table)
+                q, k, v, cfg, cache, positions, chunk_lens, block_table,
+                layer)
             out = out.reshape(b, l, cfg.n_heads * cfg.head_dim)
             return Linear.apply(params["wo"], out), new_cache
 
@@ -356,7 +402,7 @@ class Attention:
             # the contiguous per-slot cache (stale pool entries are masked by
             # their pos sentinel exactly like unwritten contiguous slots).
             assert block_table is not None, "paged cache needs a block_table"
-            ps = cache["pos"].shape[1]
+            ps = cache["pos"].shape[-1]
             ci_v = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (b,))
             rows = jnp.arange(b)
             page_idx = jnp.clip(ci_v // ps, 0, block_table.shape[1] - 1)
@@ -366,12 +412,13 @@ class Attention:
             page_ids = jnp.maximum(block_table[rows, page_idx], 0)
             off = ci_v % ps
             pos_q = jnp.broadcast_to(positions, (b, 1))
+            at = _pool_index(layer, page_ids, off)
             with jax.named_scope("kv_write"):
-                k_pages = cache["k_pages"].at[page_ids, off].set(
-                    k[:, 0].astype(cache["k_pages"].dtype))
-                v_pages = cache["v_pages"].at[page_ids, off].set(
-                    v[:, 0].astype(cache["v_pages"].dtype))
-                pos_pages = cache["pos"].at[page_ids, off].set(
+                k_pages = cache["k_pages"].at[at].set(
+                    _pool_rows(k[:, 0], cache["k_pages"]))
+                v_pages = cache["v_pages"].at[at].set(
+                    _pool_rows(v[:, 0], cache["v_pages"]))
+                pos_pages = cache["pos"].at[at].set(
                     pos_q[:, 0].astype(jnp.int32))
             new_cache = {"k_pages": k_pages, "v_pages": v_pages,
                          "pos": pos_pages}
@@ -379,7 +426,8 @@ class Attention:
             out = paged_ops.paged_attention(
                 q, k_pages, v_pages, pos_pages, block_table, pos_q,
                 scale=cfg.scale, causal=cfg.causal, window=cfg.window,
-                use_kernel=cfg.paged_kernel, kblock_pages=cfg.kblock_pages)
+                use_kernel=cfg.paged_kernel, kblock_pages=cfg.kblock_pages,
+                layer=layer, kv_heads=cfg.n_kv_heads)
         else:
             slots = cache["k"].shape[1]
             ci = jnp.asarray(cache_index, jnp.int32)
@@ -421,7 +469,7 @@ class Attention:
 
     @staticmethod
     def _chunked_decode(q, k, v, cfg: AttnConfig, cache, positions,
-                        chunk_lens, block_table):
+                        chunk_lens, block_table, layer=None):
         """Multi-token decode: write up to C cache rows per slot, then attend
         each chunk row against the full (updated) cache.
 
@@ -444,17 +492,18 @@ class Attention:
 
         if "k_pages" in cache:
             assert block_table is not None, "paged cache needs a block_table"
-            ps = cache["pos"].shape[1]
+            ps = cache["pos"].shape[-1]
             page_idx = jnp.clip(pos_q // ps, 0, block_table.shape[1] - 1)
             page_ids = jnp.maximum(block_table[rows, page_idx], 0)
             page_ids = jnp.where(row_ok, page_ids, 0)   # invalid rows: trash
             off = pos_q % ps
+            at = _pool_index(layer, page_ids, off)
             with jax.named_scope("kv_write"):
-                k_pages = cache["k_pages"].at[page_ids, off].set(
-                    k.astype(cache["k_pages"].dtype))
-                v_pages = cache["v_pages"].at[page_ids, off].set(
-                    v.astype(cache["v_pages"].dtype))
-                pos_pages = cache["pos"].at[page_ids, off].set(
+                k_pages = cache["k_pages"].at[at].set(
+                    _pool_rows(k, cache["k_pages"]))
+                v_pages = cache["v_pages"].at[at].set(
+                    _pool_rows(v, cache["v_pages"]))
+                pos_pages = cache["pos"].at[at].set(
                     jnp.where(row_ok, pos_q, -1))
             new_cache = {"k_pages": k_pages, "v_pages": v_pages,
                          "pos": pos_pages}
@@ -462,7 +511,8 @@ class Attention:
             out = paged_ops.paged_attention(
                 q, k_pages, v_pages, pos_pages, block_table, pos_q,
                 scale=cfg.scale, causal=cfg.causal, window=cfg.window,
-                use_kernel=cfg.paged_kernel, kblock_pages=cfg.kblock_pages)
+                use_kernel=cfg.paged_kernel, kblock_pages=cfg.kblock_pages,
+                layer=layer, kv_heads=cfg.n_kv_heads)
             return out, new_cache
 
         slots = cache["k"].shape[1]

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import Backbone
+from repro.models.backbone import pool_layers_in_carry
 from repro.nn.moe import SINGLE, MeshInfo
 from repro.serving.weakjit import weak_method
 
@@ -112,6 +113,13 @@ class Engine:
         # without donation support, e.g. CPU — then it simply copies).
         self._step = maybe_jit(weak_method(self._step_impl),
                                donate_argnums=(2,))
+        # Scanned layers whose paged pools (the page allocator's, under
+        # ``serving.paged``) ride the layer scan's carry.
+        self.pool_layers_in_carry = pool_layers_in_carry(jax.eval_shape(
+            lambda: Backbone.init_cache(
+                cfg, batch, self.max_len,
+                page_pool=(2, cfg.serving.page_size)))) \
+            if cfg.serving.paged else 0
         self._prime = maybe_jit(weak_method(self._prime_impl),
                                 static_argnames=("prime_len",))
 

@@ -218,8 +218,15 @@ class PagedKVSlotAllocator:
 
         if template is None:
             template = Backbone.init_cache(cfg, batch, max_len)
-        self.cache = Backbone.init_cache(
-            cfg, batch, max_len, page_pool=(self.pool_pages, ps))
+
+        def make_pool():
+            return Backbone.init_cache(cfg, batch, max_len,
+                                       page_pool=(self.pool_pages, ps))
+
+        # Made by one program, the stacked pools are written once; made op
+        # by op, each is a broadcast and then its copy, two pools' worth of
+        # device memory at once.
+        self.cache = jax.jit(make_pool)() if jit else make_pool()
         # The template may be compact (prefix-sized, from
         # ``Engine.prime(compact=True)``): paged layers import from it
         # as-is, but ineligible contiguous layers must match the live
@@ -346,7 +353,13 @@ class PagedKVSlotAllocator:
                     src = jnp.pad(src, cfgpad, constant_values=fill)
                 shape = (src.shape[:seq_ax] + (npp, ps) +
                          src.shape[seq_ax + 1:])
-                ch[pool_key] = src.reshape(shape).astype(pool.dtype)
+                # Row widths up to the pool's: K/V pools hold their heads
+                # padded to whole tiles (``nn.attention.pool_kv_heads``).
+                rows = [(0, p - c) for c, p in zip(
+                    src.shape[seq_ax + 1:], pool.shape[axis + 2:])]
+                ch[pool_key] = jnp.pad(
+                    src.reshape(shape).astype(pool.dtype),
+                    [(0, 0)] * (seq_ax + 2) + rows)
             chunks[f"{sec}/{i}"] = ch
         return chunks
 

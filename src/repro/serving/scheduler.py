@@ -508,6 +508,11 @@ class ContinuousScheduler:
         for c in self.classes:
             c.allocator.tracer = self.tracer
         self.ledger.tracer = self.tracer
+        # Whether the paged pools ride the layer scan's carry: a count
+        # fixed when the engines are built, so gauged once.
+        self.tracer.metrics.gauge(
+            "pool_layers_in_carry",
+            sum(c.engine.pool_layers_in_carry for c in self.classes))
 
     # -- queue (delegated to the admission policy) -----------------------------
 

@@ -11,6 +11,7 @@ import pytest
 from repro.configs.base import ServingConfig
 from repro.configs.registry import get_smoke_config
 from repro.models import Backbone
+from repro.nn.attention import pool_kv_heads
 from repro.serving.engine import Engine, ServeState
 from repro.serving.kvcache import KVSlotAllocator
 from repro.serving.paging import PagedKVSlotAllocator, PageTable, pages_for
@@ -80,18 +81,47 @@ def test_pool_must_hold_prefix_pages():
 # Bit-for-bit parity with the contiguous allocator
 # ---------------------------------------------------------------------------
 
-def test_paged_decode_matches_contiguous_bitwise(key):
-    """Step-level: with a dense pool and an aligned page size, the paged
-    decode path produces logits bit-for-bit equal to the contiguous path —
-    gathered pages cover the same positions in the same order, and masked
-    pool entries contribute an exact zero to the softmax."""
-    cfg = _cfg()
+def _gathered(pages, block_table, width):
+    """A paged layer's pool read back per slot in position order,
+    ``width`` positions wide: what the contiguous cache holds."""
+    bt = np.asarray(block_table)
+    g = np.asarray(pages)[np.maximum(bt, 0)]        # (B, mp, ps, ...)
+    if g.ndim == 3:                                  # pos: unmapped -> -1
+        g = np.where(bt[:, :, None] >= 0, g, -1)
+    return g.reshape((g.shape[0], -1) + g.shape[3:])[:, :width]
+
+
+@pytest.mark.parametrize("chunk,kernel,heads", [
+    (1, False, 4), (1, True, 4), (4, False, 4), (4, True, 4),
+    (1, False, 12), (4, True, 12)])
+def test_paged_decode_matches_contiguous_bitwise(key, chunk, kernel, heads):
+    """Step-level: with a dense pool, the paged decode path — its pools
+    riding the layer scan's carry, written and read in place — produces
+    logits bit-for-bit equal to the contiguous path, and its pools read
+    back through the block table equal the contiguous caches: gathered
+    pages cover the same positions in the same order, and masked pool
+    entries contribute an exact zero to the softmax.  The steps cross
+    pages, and a recycled slot's next steps reuse freed pages.  One-token
+    and 4-row chunked steps; the jnp gather and the Pallas kernel
+    (interpret mode; its online softmax matches the gather to rounding,
+    so its logits are held to the tokens they pick and the K/V its later
+    layers write to float32 rounding).  At 12 heads the pool pads each
+    page's heads to 16 (``pool_kv_heads``)."""
+    def widths(c):
+        return dataclasses.replace(c, n_heads=heads, n_kv_heads=heads,
+                                   head_dim=64)
+
+    cfg = widths(_cfg(prefill_chunk=chunk))
     params = Backbone.init(key, cfg)
     B, n = 2, cfg.mux.n
-    cfg_p = _cfg(paged=True, page_size=8)
+    cfg_p = widths(_cfg(paged=True, page_size=4, prefill_chunk=chunk,
+                        use_kernel=kernel))
     eng_c = Engine(params, cfg, batch=B, max_len=30)      # +2 prefix = 32
     eng_p = Engine(params, cfg_p, batch=B, max_len=30)
-    assert eng_c.max_len % 8 == 0
+    groups = cfg.layer_pattern()[2]
+    assert groups >= 3
+    assert eng_p.pool_layers_in_carry == groups
+    assert eng_c.pool_layers_in_carry == 0
 
     primed_c = eng_c.prime()
     alloc_c = KVSlotAllocator(cfg, B, eng_c.max_len, template=primed_c.cache)
@@ -99,45 +129,97 @@ def test_paged_decode_matches_contiguous_bitwise(key):
     alloc_p = PagedKVSlotAllocator(cfg_p, B, eng_p.max_len,
                                    template=primed_p.cache)
 
-    ones = jnp.ones((B, n), jnp.float32)
     pos = np.asarray(primed_c.pos).copy()
+    prefix = pos.copy()
     toks = jax.random.randint(key, (B, n), 0, cfg.vocab)
-    for _ in range(6):
+    for t in range(12):
+        if t == 7:                       # recycle slot 1: pages go free
+            drained = np.array([False, True])
+            alloc_c.reset_slots(drained)
+            alloc_p.reset_slots(drained)
+            pos = np.where(drained, prefix, pos)
+        if chunk == 1:
+            lens, step_toks = None, toks
+            mask = jnp.ones((B, n), jnp.float32)
+        else:
+            lens = np.array([chunk if t % 4 == 0 else 1, 1 + t % chunk],
+                            np.int32)
+            step_toks = jnp.broadcast_to(toks[..., None], (B, n, chunk))
+            mask = jnp.asarray(np.arange(chunk)[None, None, :] <
+                               lens[:, None, None], jnp.float32)
+            mask = jnp.broadcast_to(mask, (B, n, chunk))
         st_c = ServeState(cache=alloc_c.cache, pos=jnp.asarray(pos),
                           index_embeds=primed_c.index_embeds)
-        la, st_c = eng_c.step(st_c, toks, lane_mask=ones)
+        la, st_c = eng_c.step(st_c, step_toks, lane_mask=mask,
+                              chunk_lens=lens)
         alloc_c.adopt(st_c.cache)
 
-        alloc_p.ensure(pos, np.ones(B, bool))
+        alloc_p.ensure(pos, np.ones(B, bool), lens)
         st_p = ServeState(cache=alloc_p.cache, pos=jnp.asarray(pos),
                           index_embeds=primed_p.index_embeds)
-        lb, st_p = eng_p.step(st_p, toks, lane_mask=ones,
-                              block_table=alloc_p.block_table)
+        lb, st_p = eng_p.step(st_p, step_toks, lane_mask=mask,
+                              block_table=alloc_p.block_table,
+                              chunk_lens=lens)
         alloc_p.adopt(st_p.cache)
 
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-        toks = jnp.argmax(la, axis=-1)
-        pos += 1
+        if kernel:
+            np.testing.assert_array_equal(np.argmax(np.asarray(la), -1),
+                                          np.argmax(np.asarray(lb), -1))
+        else:
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+        toks = jnp.argmax(la if chunk == 1 else la[:, :, 0], axis=-1)
+        pos = pos + (1 if lens is None else lens)
+    assert alloc_p.table.free_pages < alloc_p.table.usable_pages
+
+    # The kernel's rounding reaches the K/V of the layers after the first
+    # through their inputs; the first layer's and every position are exact.
+    width = eng_c.max_len
+    for cont, pool in zip(alloc_c.cache["blocks"], alloc_p.cache["blocks"]):
+        for g in range(groups):
+            want_pos = np.asarray(cont["pos"][g])
+            got_pos = _gathered(pool["pos"][g], alloc_p.block_table, width)
+            np.testing.assert_array_equal(got_pos, want_pos)
+            live = want_pos >= 0
+            for kc, kp in (("k", "k_pages"), ("v", "v_pages")):
+                rows = _gathered(pool[kp][g], alloc_p.block_table, width)
+                assert rows.shape[-2] == pool_kv_heads(heads)
+                # Padded heads: written, never real.
+                assert not rows[live][:, heads:].any()
+                got = rows[live][:, :heads]
+                want = np.asarray(cont[kc][g])[live]
+                if kernel and g:
+                    np.testing.assert_allclose(got, want, atol=1e-5)
+                else:
+                    np.testing.assert_array_equal(got, want)
 
 
-def test_paged_scheduler_matches_contiguous_outputs(key):
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_paged_scheduler_matches_contiguous_outputs(key, chunk):
     """Trace-level: the paged scheduler reproduces the contiguous
     scheduler's outputs token-for-token on a mixed trace (admissions,
-    ramps, retirements, and slot recycles all land identically)."""
-    cfg = _cfg()
+    ramps, retirements, and slot recycles all land identically), and
+    gauges how many scanned layers' pools ride the layer scan's carry."""
+    from repro.serving.telemetry import Tracer
+    cfg = _cfg(prefill_chunk=chunk)
     params = Backbone.init(key, cfg)
     base = _requests([(3, 0), (5, 0), (2, 0), (4, 1), (6, 2), (3, 4)])
 
-    s1 = ContinuousScheduler(Engine(params, cfg, batch=2, max_len=30))
+    t1, t2 = Tracer(), Tracer()
+    s1 = ContinuousScheduler(Engine(params, cfg, batch=2, max_len=30),
+                             tracer=t1)
     st1 = s1.run(_fresh(base))
     s2 = ContinuousScheduler(
-        Engine(params, _cfg(paged=True, page_size=8), batch=2, max_len=30))
+        Engine(params, _cfg(paged=True, page_size=8, prefill_chunk=chunk),
+               batch=2, max_len=30), tracer=t2)
     st2 = s2.run(_fresh(base))
 
     assert st1.decode_steps == st2.decode_steps
     out1 = {q.rid: q.output for q in s1.finished}
     out2 = {q.rid: q.output for q in s2.finished}
     assert out1 == out2
+    assert t1.metrics.gauges["r0/pool_layers_in_carry"] == 0
+    assert t2.metrics.gauges["r0/pool_layers_in_carry"] == \
+        cfg.layer_pattern()[2]
 
 
 # ---------------------------------------------------------------------------
@@ -333,6 +415,8 @@ def test_mla_paged_decode_matches_contiguous_bitwise(key):
     cfg_p = _mla_cfg(paged=True, page_size=8)
     eng_c = Engine(params, cfg, batch=B, max_len=30)
     eng_p = Engine(params, cfg_p, batch=B, max_len=30)
+    # MLA latents keep the per-layer slices: no pool rides the carry.
+    assert eng_p.pool_layers_in_carry == 0
 
     primed_c = eng_c.prime()
     alloc_c = KVSlotAllocator(cfg, B, eng_c.max_len, template=primed_c.cache)

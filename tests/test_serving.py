@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.base import ServingConfig
 from repro.configs.registry import get_smoke_config
 from repro.models import Backbone
 from repro.serving.engine import Engine
@@ -73,13 +74,22 @@ def test_engine_generate_muxed(key):
     assert not bool(jnp.isnan(out).any())
 
 
-def test_engine_generate_unmuxed(key):
+@pytest.mark.parametrize("paged", [False, True])
+def test_engine_generate_unmuxed(key, paged):
+    """Lock-step generation; under a paged configuration ``generate``
+    decodes from prefill's contiguous cache, as the unpaged engine does."""
     cfg = get_smoke_config("qwen1.5-4b", mux_n=1)
     params = Backbone.init(key, cfg)
     eng = Engine(params, cfg, batch=2, max_len=12)
     prompts = jax.random.randint(key, (2, 6), 0, cfg.vocab)
     out = eng.generate(prompts, 4)
     assert out.shape == (2, 5)
+    if paged:
+        cfg_p = dataclasses.replace(
+            cfg, serving=ServingConfig(paged=True, page_size=4))
+        eng_p = Engine(params, cfg_p, batch=2, max_len=12)
+        assert eng_p.pool_layers_in_carry == cfg.layer_pattern()[2]
+        np.testing.assert_array_equal(eng_p.generate(prompts, 4), out)
 
 
 def test_sliding_window_ring_buffer(key):

@@ -7,11 +7,20 @@ the TPU compiler for one chip of a *described* v5e:2x2 — no chip attached,
 nothing runs — and the compiled program must hold the kernel as a
 ``tpu_custom_call``.  Widths: d 2560, 20 heads of 128, mux N = 8 (and the
 paper's N = 40 at d 768 for the mux), bf16.
+
+The engine's paged decode step is compiled the same way, at qwen1.5-4b's
+attention widths: the stacked K/V pools must be updated in place, with no
+slice, copy or fresh buffer of a pool, in the row-major layout of the
+step's writes, and every page program must take and return the pool in
+that layout.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -104,3 +113,108 @@ def test_paged_decode_attention_compiles(one_chip, ps, kblock, c):
         S((pool, ps, HEADS, HD)), S((pool, ps), jnp.int32),
         S((b, max_pages), jnp.int32), S((b, c), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# The paged decode step and the page programs
+# ---------------------------------------------------------------------------
+
+LAYERS, SLOTS, PAGE = 3, 2, 128
+# Ops that would move a whole pool (or one layer's pool out of the stack):
+# XLA names a fusion after the ops it fuses, so the names count too.
+POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice", "broadcast",
+              "AllocateBuffer")
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = (\S+?)\{.*?\} (\S+?)\((.*)")
+
+
+def _qwen_paged(chunk):
+    """qwen1.5-4b's attention and MLP widths over three scanned layers,
+    a small vocabulary, mux N = 8, 128-position pages."""
+    from repro.configs.base import MuxConfig, ServingConfig
+    from repro.configs.registry import get_config
+    return dataclasses.replace(
+        get_config("qwen1.5-4b"), n_layers=LAYERS, vocab=1024,
+        mux=MuxConfig(n=N, strategy="hadamard", demux="index_embed"),
+        serving=ServingConfig(paged=True, page_size=PAGE,
+                              prefill_chunk=chunk))
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _pool_layouts(formats):
+    return [{k: f.layout for k, f in layer.items()}
+            for layer in formats["blocks"]]
+
+
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_paged_decode_step_updates_pools_in_place(one_chip, chunk):
+    from repro.models import Backbone
+    from repro.serving.engine import Engine
+    from repro.serving.paging import PagedKVSlotAllocator
+    cfg = _qwen_paged(chunk)
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: Backbone.init(k, cfg), jax.random.PRNGKey(0)))
+    eng = Engine(params, cfg, batch=SLOTS, max_len=2 * PAGE)
+    assert eng.pool_layers_in_carry == LAYERS
+
+    # The page allocator at the same structure, built on the host; its
+    # programs are compiled for the described chip from their shapes.
+    alloc = PagedKVSlotAllocator(cfg, SLOTS, eng.max_len)
+    cache = _on(one_chip, alloc.cache)
+    mp = alloc.pages_per_slot
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    lanes = (SLOTS, N) if chunk == 1 else (SLOTS, N, chunk)
+    step = eng._step.lower(
+        params, i32(*lanes), cache, i32(SLOTS),
+        jax.ShapeDtypeStruct((SLOTS, N, cfg.d_model), cfg.compute_dtype,
+                             sharding=one_chip),
+        None, jax.ShapeDtypeStruct(lanes, jnp.float32, sharding=one_chip),
+        i32(SLOTS, mp), None if chunk == 1 else i32(SLOTS)).compile()
+
+    pool = alloc.cache["blocks"][0]["k_pages"]
+    shapes = {f"bf16[{','.join(map(str, s))}]"
+              for s in (pool.shape, pool.shape[1:])}
+    moved = []
+    for line in step.as_text().splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(2) in shapes and any(
+                w in m.group(1) or w in m.group(3) or
+                (m.group(3) == "custom-call" and w in m.group(4))
+                for w in POOL_MOVES):
+            moved.append(f"{m.group(1)} {m.group(3)}")
+    assert not moved, moved
+
+    want = _pool_layouts(step.input_formats[0][2])
+    assert want[0]["k_pages"].major_to_minor == (0, 1, 2, 3, 4)
+    assert _pool_layouts(step.output_formats[1]) == want
+    npp = alloc.n_prefix_pages
+    snap = alloc._snapshot_impl(alloc.cache, 0)
+    programs = {
+        "invalidate": (alloc._invalidate, (cache, i32(SLOTS))),
+        "reset": (alloc._reset, (cache, _on(one_chip, alloc.template),
+                                 _on(one_chip, np.zeros(SLOTS, bool)),
+                                 i32(SLOTS))),
+        "import": (alloc._import, (cache, _on(one_chip, alloc.template),
+                                   _on(one_chip, alloc._prefix_chunks),
+                                   i32(SLOTS, npp))),
+        "import_slot": (alloc._import_slot,
+                        (cache, _on(one_chip, alloc._prefix_chunks),
+                         i32(npp), i32())),
+        "snapshot": (alloc._snapshot, (cache, i32())),
+        "restore": (alloc._restore, (cache, _on(one_chip, snap), i32())),
+    }
+    for name, (prog, args) in programs.items():
+        compiled = prog.lower(*args).compile()
+        got = _pool_layouts(compiled.input_formats[0][0])
+        if name == "snapshot":
+            # It copies out the contiguous layers only; qwen has none, so
+            # the pool is an unused argument and pruned (no layout).
+            assert all(v is None for layer in got for v in layer.values())
+            continue
+        assert got == want, name
+        assert _pool_layouts(compiled.output_formats) == want, name
