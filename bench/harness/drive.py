@@ -24,7 +24,7 @@ from typing import Optional
 
 import numpy as np
 
-from harness import spec, traffic as traffic_gen, weights
+from harness import readers, spec, traffic as traffic_gen, weights
 
 
 @dataclasses.dataclass
@@ -52,6 +52,10 @@ class Served:
     refused: int = 0
     inputs_offset: int = 0           # scheduler t of inputs[0]
     waiting: tuple = (0, 0)          # queued requests at window open, close
+    # host-clock [open, close) of the traffic's window segment, on the
+    # schedule: the requests due in it are the window's, the same sizes
+    # for every seed (the window itself opens and closes on step ends)
+    due_window: tuple = (0.0, 0.0)
 
 
 def _emits(slo):
@@ -163,6 +167,7 @@ def _loop(sched, emits, inputs, config, traffic, *, seed, seconds, cfg,
     warm_end = t_base + traffic["warmup_s"]
     for it in items:
         out.due[it.rid] = t_base + it.due_s
+    out.due_window = (warm_end, warm_end + seconds)
     phase, t0, t1, drain_end = "warm", None, None, None
     tracing = False
     window_due: list = []
@@ -221,7 +226,7 @@ def _loop(sched, emits, inputs, config, traffic, *, seed, seconds, cfg,
             # The drain runs from here: stopping a profile can take tens of
             # seconds on the chip, which must not eat into it.
             drain_end = time.perf_counter() + drain_s
-            window_due = [rid for rid, d in out.due.items() if t0 <= d < t1]
+            window_due = readers.window_due(out)
         if phase == "drain":
             waiting_first = any(not out.tok_times.get(r)
                                 for r in window_due)

@@ -9,8 +9,10 @@ import numpy as np
 
 
 def window_due(served) -> list:
-    t0, t1 = served.window
-    return [r for r, d in served.due.items() if t0 <= d < t1]
+    """The requests of the traffic's window segment: due in the window's
+    scheduled seconds, whatever step the window opened and closed on."""
+    lo, hi = served.due_window
+    return [r for r, d in served.due.items() if lo <= d < hi]
 
 
 def ttft_ms(served) -> list:
@@ -39,3 +41,24 @@ def itl_ms(served) -> list:
 def percentile(values: list, q: float):
     return float(np.percentile(values, q)) if values else None
 
+
+def roofline_share(ctx, scope: str):
+    """Percent of the chip's HBM peak that a scope of the decode step
+    reaches: the bytes it must move per traced step (``hbm.step``, averaged
+    over the traced steps) over its device seconds per step
+    (``scopes.scope_ms``) times the peak."""
+    from harness import drive, hbm, scopes
+    if ctx.peak_hbm is None:
+        return None
+    ms = scopes.scope_ms(ctx, scope)
+    if not ms:
+        return None
+    served = ctx.served
+    lo, hi = served.traced_steps
+    got = [hbm.step(served.config, inp.mask, inp.pos)[scope]
+           for inp in (drive.input_at(served, t) for t in range(lo, hi))
+           if inp is not None]
+    if not got or not sum(got):
+        return None
+    return (sum(got) / len(got) / (ms * 1e-3 * ctx.peak_hbm * ctx.n_chips)
+            * 100.0)

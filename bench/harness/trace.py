@@ -2,10 +2,11 @@
 step device time and the breakdown of where the time went.
 
 Device planes are those named ``/device:TPU:<n>``; an operation is an event
-of their ``XLA Ops`` line.  Host spans are the events of the host's Python
+of their ``XLA Ops`` line, and runs inside the program of the ``XLA
+Modules`` event around it.  Host spans are the events of the host's Python
 thread line (``python`` or ``python3``): the harness's own
-``TraceAnnotation`` spans (``step``, ``submit``, ``wait_arrival``) and JAX's
-events inside them.
+``TraceAnnotation`` spans (``step``, ``submit``, ``wait_arrival``), the
+program's own spans and JAX's events inside them, each with its stats.
 
 The device's clock in the trace runs about a millisecond off the host's
 (1.27 ms early on the recorded fixture), more than a short step's device
@@ -18,6 +19,7 @@ device idle, and started at once).
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import glob
@@ -31,6 +33,10 @@ OP_LINE = "XLA Ops"
 class Trace:
     ops: dict          # device plane name -> [(name, start_ns, end_ns)]
     host: list         # [(name, start_ns, end_ns, depth)] on the python line
+    # [(name, start_ns, end_ns, stats)] of every event on the python line
+    python: list = dataclasses.field(default_factory=list)
+    # device plane name -> [(start_ns, end_ns, program)], sorted
+    modules: dict = dataclasses.field(default_factory=dict)
 
     def spans(self, name: str) -> list:
         return [(a, b) for n, a, b, _ in self.host if n == name]
@@ -52,13 +58,20 @@ def _op_name(text: str) -> str:
     return text.split(" = ", 1)[0].lstrip("%")
 
 
+def _program(name: str) -> str:
+    """``XLA Modules`` events are named ``<module>(<program id>)``."""
+    return name.split("(", 1)[0]
+
+
 def load(path: str) -> Trace:
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     ops: dict = {}
     starts: dict = {}        # (device ordinal, run_id) -> device start
     enqueued: dict = {}      # (device ordinal, run_id) -> host enqueue
+    modules: dict = {}
     host: list = []
+    python: list = []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             ordinal = int(plane.name[len(DEVICE_PREFIX):])
@@ -67,14 +80,19 @@ def load(path: str) -> Trace:
                     ops[plane.name] = [(_op_name(e.name), e.start_ns,
                                         e.end_ns) for e in line.events]
                 elif line.name == "XLA Modules":
+                    mods = modules.setdefault(plane.name, [])
                     for e in line.events:
+                        mods.append((e.start_ns, e.end_ns, _program(e.name)))
                         run = dict(e.stats).get("run_id")
                         if run is not None:
                             starts[(ordinal, run)] = e.start_ns
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 if line.name.startswith("python"):
-                    host.extend(_nest(line.events))
+                    evs = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+                           for e in line.events]
+                    python.extend(evs)
+                    host.extend(_nest(evs))
                 for e in line.events:
                     if e.name == "DoEnqueueProgram":
                         st = dict(e.stats)
@@ -86,17 +104,20 @@ def load(path: str) -> Trace:
                   if k[0] == ordinal and k in enqueued]
         shift = max(shifts) if shifts else 0.0
         ops[name] = [(n, a + shift, b + shift) for n, a, b in ops[name]]
-    return Trace(ops=ops, host=host)
+        modules[name] = sorted((a + shift, b + shift, p)
+                               for a, b, p in modules.get(name, []))
+    return Trace(ops=ops, host=host, python=python, modules=modules)
 
 
 def _nest(events) -> list:
-    """Events of one thread line with their nesting depth."""
+    """Events ``(name, start, end, ...)`` of one thread line with their
+    nesting depth."""
     out, stack = [], []
-    for e in sorted(events, key=lambda e: (e.start_ns, -e.end_ns)):
-        while stack and stack[-1] <= e.start_ns:
+    for name, a, b, *_ in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1] <= a:
             stack.pop()
-        out.append((e.name, e.start_ns, e.end_ns, len(stack)))
-        stack.append(e.end_ns)
+        out.append((name, a, b, len(stack)))
+        stack.append(b)
     return out
 
 
@@ -113,8 +134,17 @@ def union(intervals) -> list:
 
 
 def covered(merged: list, a: float, b: float) -> float:
-    """Length of the part of [a, b] that the merged intervals cover."""
-    return sum(max(0.0, min(b, y) - max(a, x)) for x, y in merged)
+    """Length of the part of [a, b] that the merged intervals (sorted and
+    disjoint, as ``union`` gives them) cover; only those that reach past
+    ``a`` are visited, so a trace of millions of operations is read in
+    one pass over its steps."""
+    k = bisect.bisect_right(merged, a, key=lambda iv: iv[1])
+    tot = 0.0
+    while k < len(merged) and merged[k][0] < b:
+        x, y = merged[k]
+        tot += min(b, y) - max(a, x)
+        k += 1
+    return tot
 
 
 @dataclasses.dataclass

@@ -5,4 +5,4 @@ from harness import scopes
 
 
 def read(ctx):
-    return scopes.readback_bytes(ctx)
+    return scopes.span_stat(ctx, "sched.readback", "bytes")

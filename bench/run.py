@@ -11,7 +11,8 @@ float32 reference, and prints as its last line one JSON object:
 with ``--trace 0``, the per-layer ones with ``--trace 1``), ``device``,
 with ``--trace 1`` a ``breakdown``, and last ``check``: each number
 compared, with its limit.  A ``--trace 1`` run profiles the last ten
-seconds of its window.
+seconds of its window and hands the readers the program's spans and the
+decode step's scopes (``harness/scopes.py``).
 
 Exits 2, printing no result, when JAX finds no TPU or fewer chips than the
 cell asks for: there is no CPU fallback.
@@ -76,16 +77,21 @@ def enable_compile_cache() -> str:
 
 
 class Context:
-    """What the metric readers read."""
+    """What the metric readers read: the run (``served``), its set-up
+    times, the chip's peaks, and in a ``--trace 1`` run the trace's
+    reduction (``trace``) and the program's spans and scopes (``layers``,
+    ``harness/scopes.py``)."""
 
     def __init__(self, served, setup_s, compile_setup_s, red, peak_flops,
-                 n_chips):
+                 n_chips, peak_hbm=None, layers=None):
         self.served = served
         self.setup_s = setup_s
         self.compile_setup_s = compile_setup_s
         self.trace = red
         self.peak_flops = peak_flops
+        self.peak_hbm = peak_hbm
         self.n_chips = n_chips
+        self.layers = layers
 
 
 def main(argv=None, *, require_tpu: bool = True) -> dict:
@@ -110,10 +116,13 @@ def main(argv=None, *, require_tpu: bool = True) -> dict:
     say(f"device: {devices[0].device_kind} x {len(devices)}; compile cache "
         f"{enable_compile_cache()}")
 
-    from harness import check, drive, peaks, trace as tracing
+    from harness import check, drive, peaks, scopes, trace as tracing
     from harness.compile_clock import CompileClock
     clock = CompileClock()
     cfg, engine = drive.build_engine(cell.config, args.seed)
+    # A traced run keeps the decode step's arguments, to read the scopes
+    # of the program that ran; an untraced run leaves the engine as it is.
+    held = scopes.record_step_args(engine) if args.trace else None
 
     annotate = jax.profiler.TraceAnnotation
     marks: dict = {}
@@ -151,30 +160,48 @@ def main(argv=None, *, require_tpu: bool = True) -> dict:
     peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in devices[:cell.chips])
 
-    red = breakdown = None
+    red = breakdown = layers = None
     if args.trace:
+        t = time.perf_counter()
         tr = tracing.load(tracing.find(str(TRACE_DIR)))
         red = tracing.reduce(tr)
+        t_read = time.perf_counter()
         if red is None:
             say("the trace holds no device operations")
         else:
             breakdown = tracing.breakdown(tr, red)
+            hlo = scopes.step_hlo(held)
+            t_hlo = time.perf_counter()
+            layers = scopes.load(hlo, tr)
+            say(f"trace: read {t_read - t:.2f} s, decode program's HLO "
+                f"{t_hlo - t_read:.2f} s, ops by program and scope "
+                f"{time.perf_counter() - t_hlo:.2f} s")
+        del tr
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    # Peaks of a chip not in the table are an error; a run without the chip
+    # has none, unless a test puts its device in the table.
+    kind = devices[0].device_kind
+    chip = peaks.peaks(kind) if devices[0].platform == "tpu" \
+        else peaks.PEAKS.get(kind)
     ctx = Context(served, setup_s=served.window[0] - T_PROCESS,
                   compile_setup_s=c_open, red=red,
-                  peak_flops=peaks.peaks(devices[0].device_kind).bf16_flops
-                  if devices[0].platform == "tpu" else None,
-                  n_chips=cell.chips)
+                  peak_flops=chip and chip.bf16_flops,
+                  peak_hbm=chip and chip.hbm_bytes,
+                  n_chips=cell.chips, layers=layers)
     wanted = cell.per_layer if args.trace else cell.end_to_end
     metrics = {}
+    t = time.perf_counter()
     for m in wanted:
         value = load_reader(m["name"])(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if layers is not None:
+        say(f"readers: {time.perf_counter() - t:.2f} s; top ops of each "
+            f"scope, ms per step: {json.dumps(scopes.top_ops(ctx))}")
     attempted, failed = attempts(served)
 
     # The program's state goes before the reference runs.
-    del engine, ctx
+    del engine, held, ctx, layers
     gc.collect()
     got = check.judge(cell.config, cfg, args.seed, served)
     correct, compared = got["program"]

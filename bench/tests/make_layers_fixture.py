@@ -80,16 +80,19 @@ def main(out: str) -> None:
                   [e.name[:60] for e in evs[:3]])
     tr = trace.load(xplane)
     red = trace.reduce(tr)
-    lay = scopes.load(xplane, (path / "layers.hlo.txt").read_text(), tr)
+    lay = scopes.load((path / "layers.hlo.txt").read_text(), tr)
     print("programs", collections.Counter(
-        p for evs in lay.ops.values() for _, p, _, _ in evs))
-    print("scopes in the HLO", collections.Counter(lay.scope_of.values()))
+        p for groups in lay.ops.values() for (p, _), xs in groups.items()
+        for _ in xs))
+    print("scopes in the HLO", collections.Counter(
+        scopes.innermost(p) for p in lay.scope_of.values() if p is not None))
     ctx = types.SimpleNamespace(trace=red, layers=lay)
     print("steps", len(red.steps), "step_device_s", red.step_device_s(),
           "step_host_s", red.step_host_s())
     for span in scopes.HOST_SPANS:
         print(span, scopes.host_ms(ctx, span))
-    print("readback bytes", scopes.readback_bytes(ctx))
+    print("readback bytes",
+          scopes.span_stat(ctx, "sched.readback", "bytes"))
     for scope in scopes.SCOPES + (scopes.UNSCOPED,):
         print(scope, scopes.scope_ms(ctx, scope))
     print("page programs", scopes.program_ms(ctx, scopes.PAGE_PROGRAMS))

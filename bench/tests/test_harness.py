@@ -123,6 +123,72 @@ def test_new_cell_from_new_files_only(checkout, tmp_path):
     assert "compile_s" in out["metrics"]
 
 
+# The trace branch of run.main on the CPU with the chip's trace substituted:
+# the small TPU trace and decode-program HLO of test_scopes.py stand in for
+# the run's own, and the CPU is given the v5e's peaks.
+TPU_TRACE = """
+from harness import peaks, scopes, trace
+trace.find = lambda log_dir: {xplane!r}
+scopes.step_hlo = lambda held: open({hlo!r}).read()
+peaks.PEAKS["cpu"] = peaks.PEAKS["TPU v5 lite"]
+""".format(xplane=str(BENCH / "tests" / "data" / "layers.xplane.pb"),
+           hlo=str(BENCH / "tests" / "data" / "layers.hlo.txt"))
+
+
+@pytest.mark.parametrize("cell", ["tiny-batch", "tiny-chat"])
+def test_trace_run_hands_every_reader_the_layers(checkout, cell):
+    """A ``--trace 1`` run gives the readers the program's spans and the
+    decode step's scopes: every per-layer metric that BENCHMARK.json gives
+    the cell reads a number."""
+    proc = run_cell(checkout, "--workload", cell, "--seed", "2147483999",
+                    "--seconds", "2", "--trace", "1", patch=TPU_TRACE)
+    out = last_json(proc)
+    assert out["correct"] is True
+    from harness import spec
+    want = {m["name"] for m in spec.load_cell(cell, checkout).per_layer}
+    assert set(out["metrics"]) == want, want ^ set(out["metrics"])
+    assert all(isinstance(m["value"], (int, float))
+               for m in out["metrics"].values())
+    assert "ops by program and scope" in proc.stderr
+    assert out["device"]["busy_s"] > 0
+
+
+def test_new_scope_and_span_readers_from_new_files_only(checkout, tmp_path):
+    """A later change reads a nested scope and a span's stat with a reader
+    file each and entries in BENCHMARK.json; the harness runs unedited."""
+    ck = tmp_path / "ck"
+    shutil.copytree(checkout, ck, symlinks=True,
+                    ignore=shutil.ignore_patterns(".jax_cache", "bench_out"))
+    before = {p: p.read_bytes() for p in (ck / "bench").rglob("*.py")}
+    metrics = ck / "bench" / "metrics"
+    (metrics / "attention_nested_ms_per_step.py").write_text(
+        "from harness import scopes\n\n\n"
+        "def read(ctx):\n"
+        "    return scopes.scope_path_ms(ctx, 'attention')\n")
+    (metrics / "execute_linkage_pt.py").write_text(
+        "from harness import scopes\n\n\n"
+        "def read(ctx):\n"
+        "    return scopes.span_stat(\n"
+        "        ctx, 'PJRT_LoadedExecutable_Execute linkage', '_pt')\n")
+    b = json.loads((ck / "BENCHMARK.json").read_text())
+    for name, unit in (("attention_nested_ms_per_step", "ms"),
+                       ("execute_linkage_pt", "1")):
+        b["per_layer"].append({"name": name, "unit": unit, "better": "lower",
+                               "source": "device_trace", "layer": "kernels",
+                               "moves": "tokens_per_s",
+                               "workloads": ["tiny-batch"]})
+    (ck / "BENCHMARK.json").write_text(json.dumps(b))
+    assert all(p.read_bytes() == v for p, v in before.items())
+
+    out = last_json(run_cell(ck, "--workload", "tiny-batch", "--seed", "23",
+                             "--seconds", "2", "--trace", "1",
+                             patch=TPU_TRACE))
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert got["execute_linkage_pt"] == 14
+    assert got["attention_nested_ms_per_step"] == pytest.approx(
+        got["attention_ms_per_step"] + got["kv_write_ms_per_step"], rel=1e-6)
+
+
 def _metrics():
     b = json.loads((ROOT / "BENCHMARK.json").read_text())
     return [m["name"] for m in b["end_to_end"] + b["per_layer"]]

@@ -2,11 +2,12 @@
 from __future__ import annotations
 
 import json
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
-from harness import traffic
+from harness import readers, traffic
 from conftest import BENCH
 
 FILES = sorted((BENCH / "traffic").glob("*.json"))
@@ -97,3 +98,17 @@ def test_backlog_is_due_at_once():
     items = traffic.generate(t, seed=4, vocab=100, seconds=40)
     assert len(items) == t["requests"]
     assert {x.due_s for x in items} == {0.0}
+
+
+def test_the_window_holds_its_segment_whatever_step_opens_it():
+    """The window opens and closes on step ends, which may lag the
+    traffic's window segment by a step; its requests are the segment's all
+    the same, so every run and every seed count the same sizes."""
+    items = traffic.generate(POISSON, seed=BIG_SEED, vocab=100, seconds=30)
+    base = 1000.0
+    late = SimpleNamespace(due={x.rid: base + x.due_s for x in items},
+                           due_window=(base + 10.0, base + 40.0),
+                           window=(base + 10.5, base + 40.5))
+    segment = [x.rid for x in items if 10.0 < x.due_s < 40.0]
+    assert len(segment) == 150
+    assert sorted(readers.window_due(late)) == segment
